@@ -14,12 +14,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.metrics import MetricRegistry, new_run_id, percentile_summary
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import kmeans
 from repro.pilot.api import PilotComputeService, PilotDescription
 from repro.streaming.broker import Broker
 from repro.streaming.engine import ThreadedStreamingEngine, Workload
 
 N_MESSAGES, POINTS, DIM, CENTROIDS = 24, 512, 9, 32
+use_compile_cache()
 
 # 1. resources: a pilot on the local backend (swap the URL for
 #    serverless://aws-sim, hpc://wrangler-sim, or jax://mesh)

@@ -74,7 +74,7 @@ def analyze_file(path: str, rel: str, manifest: Manifest,
     if manifest.is_test_file(rel):
         run_test_audit(ctx)
     elif not manifest.is_test_exempt(rel):
-        # conftest/_hypothesis_compat are exempt from EVERY pass, not
+        # conftest is exempt from EVERY pass, not
         # just the test audit: they are harness plumbing
         run_purity_pass(ctx)
         run_des_pass(ctx)

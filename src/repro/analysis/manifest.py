@@ -227,7 +227,6 @@ DEFAULT_MANIFEST = Manifest(
     ),
     test_exempt=(
         "*/tests/conftest.py",              # implements wait_until
-        "*/tests/_hypothesis_compat.py",    # vendored shim
     ),
     exclude=(
         "*simlint_fixtures*",               # known-bad corpus, tested apart

@@ -72,6 +72,7 @@ import itertools
 import json
 import multiprocessing
 import os
+import sys
 import threading
 from dataclasses import dataclass, field
 from pathlib import Path
@@ -347,6 +348,15 @@ def _run_cell_chunk(exps: list) -> list[tuple]:
     return out
 
 
+def _pin_worker_to_cpu() -> None:
+    """Pool initializer: workers simulate on the host and never start an
+    accelerator backend.  A chip belongs to one process, the parent that
+    drives it; a worker that reached for it would fail or hang."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    if "jax" in sys.modules:   # the forkserver may preload __main__'s imports
+        sys.modules["jax"].config.update("jax_platforms", "cpu")
+
+
 def _mp_context():
     """Never fork a potentially JAX-multithreaded parent (fork after jax
     import is a documented deadlock hazard); forkserver forks workers from
@@ -396,7 +406,8 @@ def _get_pool(workers: int) -> concurrent.futures.ProcessPoolExecutor:
             if _pool is not None:
                 _pool.shutdown(wait=False, cancel_futures=True)
             _pool = concurrent.futures.ProcessPoolExecutor(
-                max_workers=workers, mp_context=_mp_context())
+                max_workers=workers, mp_context=_mp_context(),
+                initializer=_pin_worker_to_cpu)
             _pool_workers = workers
         return _pool
 

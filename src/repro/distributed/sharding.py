@@ -22,26 +22,7 @@ import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 __all__ = ["Rules", "DEFAULT_RULES", "use_rules", "current_rules", "constrain",
-           "logical_to_pspec", "named_sharding", "shard_map"]
-
-
-def shard_map(fn, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-skew compat wrapper around jax's shard_map.
-
-    Newer jax exports ``jax.shard_map`` (replication checking controlled by
-    ``check_vma``); older releases only ship
-    ``jax.experimental.shard_map.shard_map`` with the same knob spelled
-    ``check_rep``.  All manual-collective call sites (MoE expert
-    parallelism) go through this wrapper so a single interpreter can run
-    either API generation.
-    """
-    impl = getattr(jax, "shard_map", None)
-    if impl is not None:
-        return impl(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                    check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as legacy_impl
-    return legacy_impl(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                       check_rep=check_vma)
+           "logical_to_pspec", "named_sharding"]
 
 
 @dataclass

@@ -40,7 +40,10 @@ def _dist_tile(x_blk, c_blk):
     c32 = c_blk.astype(jnp.float32)
     xn = jnp.sum(x32 * x32, axis=-1, keepdims=True)          # (bn, 1)
     cn = jnp.sum(c32 * c32, axis=-1, keepdims=True).T        # (1, bc)
+    # full f32 contraction: ||x||^2 + ||c||^2 - 2 x.c cancels, and the
+    # chip's default f32 matmul precision (bf16 passes) would swamp it
     dot = jax.lax.dot_general(x32, c32, (((1,), (1,)), ((), ())),
+                              precision=jax.lax.Precision.HIGHEST,
                               preferred_element_type=jnp.float32)
     return jnp.maximum(xn + cn - 2.0 * dot, 0.0)
 
@@ -89,8 +92,8 @@ def _assign_kernel(x_ref, c_ref, labels_ref, best_ref):
         labels_ref[...] = jnp.zeros_like(labels_ref)
 
     d2 = _dist_tile(x_ref[...], c_ref[...])                  # (bn, bc)
-    blk_best = jnp.min(d2, axis=1)                           # (bn,)
-    blk_arg = jnp.argmin(d2, axis=1).astype(jnp.int32)       # (bn,)
+    blk_best = jnp.min(d2, axis=1, keepdims=True)            # (bn, 1)
+    blk_arg = jnp.argmin(d2, axis=1, keepdims=True).astype(jnp.int32)
     bc = d2.shape[1]
     cur_best = best_ref[...]
     take = blk_best < cur_best
@@ -103,7 +106,9 @@ def assign_pallas(x: jax.Array, c: jax.Array, *,
                   block_n: int = DEFAULT_BLOCK_N,
                   block_c: int = DEFAULT_BLOCK_C,
                   interpret: bool = False):
-    """Fused K-Means assignment: returns (labels (n,) int32, best (n,) f32)."""
+    """Fused K-Means assignment: returns (labels (n, 1) int32, best (n, 1)
+    f32).  The outputs are 2-D because Mosaic refuses 1-D ``(block_n,)``
+    output blocks: their XLA and Mosaic tilings differ."""
     n, d = x.shape
     k, _ = c.shape
     grid = (n // block_n, k // block_c)   # trailing axis: centroid panels
@@ -115,12 +120,12 @@ def assign_pallas(x: jax.Array, c: jax.Array, *,
             pl.BlockSpec((block_c, d), lambda i, j: (j, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((block_n,), lambda i, j: (i,)),
-            pl.BlockSpec((block_n,), lambda i, j: (i,)),
+            pl.BlockSpec((block_n, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((block_n, 1), lambda i, j: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n,), jnp.int32),
-            jax.ShapeDtypeStruct((n,), jnp.float32),
+            jax.ShapeDtypeStruct((n, 1), jnp.int32),
+            jax.ShapeDtypeStruct((n, 1), jnp.float32),
         ],
         interpret=interpret,
     )(x, c)

@@ -69,4 +69,4 @@ def assign(x: jax.Array, c: jax.Array, *, use_pallas: bool | None = None,
         cp = jnp.concatenate([cp[:k], sentinel], axis=0)
     labels, best = _k.assign_pallas(xp, cp, block_n=bn, block_c=bc,
                                     interpret=interpret)
-    return labels[:n], best[:n]
+    return labels[:n, 0], best[:n, 0]

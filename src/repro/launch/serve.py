@@ -21,6 +21,7 @@ import numpy as np
 
 from repro.configs.base import get_config
 from repro.core.metrics import MetricRegistry, new_run_id, percentile_summary
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import model as M
 from repro.pilot.api import PilotComputeService, PilotDescription
 from repro.streaming.broker import Broker
@@ -38,6 +39,7 @@ def main() -> None:
     ap.add_argument("--batch-max", type=int, default=4)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch, reduced=args.reduced)
     params = M.init_params(jax.random.PRNGKey(args.seed), cfg)

@@ -20,6 +20,8 @@ import numpy as np
 
 from repro.configs.base import ShapeSpec, get_config
 from repro.data.pipeline import SyntheticLM
+from repro.launch.compile_cache import use_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import build_train
 from repro.models import model as M
 from repro.training.checkpoint import CheckpointManager, latest_step
@@ -43,6 +45,7 @@ def main() -> None:
     ap.add_argument("--mesh", default=None,
                     help="e.g. '2x4' to train on a (data, model) device mesh")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch, reduced=args.reduced)
     opt_cfg = OptimizerConfig(lr=args.lr, warmup_steps=min(20, args.steps // 10 + 1),
@@ -54,7 +57,7 @@ def main() -> None:
 
     if args.mesh:
         shape = tuple(int(x) for x in args.mesh.split("x"))
-        mesh = jax.make_mesh(shape, ("data", "model")[:len(shape)])
+        mesh = make_mesh(shape, ("data", "model")[:len(shape)])
         spec = ShapeSpec("cli", args.seq, args.batch, "train")
         with mesh:
             step_fn, _, _ = build_train(cfg, spec, mesh,

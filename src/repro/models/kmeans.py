@@ -56,7 +56,10 @@ def minibatch_step(state: KMeansState, points: jax.Array) -> KMeansState:
     c = state.centroids.shape[0]
     onehot = jax.nn.one_hot(labels, c, dtype=points.dtype)          # (n, c)
     batch_counts = onehot.sum(axis=0)                               # (c,)
-    batch_sums = onehot.T @ points                                  # (c, d)
+    # full f32: at the chip's default precision the points would be
+    # rounded to bf16 on the way through the MXU
+    batch_sums = jnp.matmul(onehot.T, points,
+                            precision=jax.lax.Precision.HIGHEST)    # (c, d)
     new_counts = state.counts + batch_counts
     # decaying per-centroid rate; centroids with no assignments unchanged
     eta = jnp.where(new_counts > 0, batch_counts / jnp.maximum(new_counts, 1.0), 0.0)

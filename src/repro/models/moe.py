@@ -34,7 +34,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.sharding import constrain, current_rules, shard_map
+from repro.distributed.sharding import constrain, current_rules
 from repro.models.layers import dense_init
 
 __all__ = ["moe_init", "moe_specs", "apply_moe", "apply_moe_local",
@@ -207,8 +207,8 @@ def apply_moe(p, cfg, x):
     x_spec = P(batch_axes, "model" if seq_sharded else None, None)
     p_specs = {"router": P(None, None), "w_gate": P("model", "data", None),
                "w_up": P("model", "data", None), "w_down": P("model", "data", None)}
-    fn = shard_map(shard_fn, mesh=mesh, in_specs=(p_specs, x_spec),
-                   out_specs=x_spec, check_vma=False)
+    fn = jax.shard_map(shard_fn, mesh=mesh, in_specs=(p_specs, x_spec),
+                       out_specs=x_spec, check_vma=False)
     return fn(p, x).astype(x.dtype)
 
 

@@ -66,6 +66,9 @@ class JaxMeshBackend(Backend):
         try:
             with pilot.mesh:
                 out = cu.desc.func(*cu.desc.args, **cu.desc.kwargs) if cu.desc.func else None
+                # dispatch is asynchronous: wait for the device, so that a
+                # device error fails this unit and end_ts is the device's end
+                jax.block_until_ready(out)
             cu._set_done(time.perf_counter(), out)
         except BaseException as exc:  # noqa: BLE001
             cu._set_failed(time.perf_counter(), exc)

@@ -1,10 +1,5 @@
-"""Suite-wide setup: import paths, the hypothesis fallback shim, and the
-wall-clock deadline helper.
-
-Runs before any test module is collected, so the ``from hypothesis import
-...`` lines in the property-test modules resolve even where hypothesis is
-not installable (the shim in ``_hypothesis_compat`` is registered in
-``sys.modules`` only when the real package is absent).
+"""Suite-wide setup: import paths, opt-in lock-order instrumentation, and
+the wall-clock deadline helper.
 
 ``wait_until`` is the suite's condition-polling primitive for wall-clock
 (threaded-engine) tests: every wait is a *condition with a deadline*,
@@ -21,10 +16,6 @@ _HERE = Path(__file__).resolve().parent
 for p in (str(_HERE), str(_HERE.parent / "src")):
     if p not in sys.path:
         sys.path.insert(0, p)
-
-import _hypothesis_compat  # noqa: E402
-
-_hypothesis_compat.install()
 
 # Opt-in lock-order instrumentation: when SIMLINT_LOCKWATCH_OUT names an
 # output path, every threading.Lock/RLock/Condition created by this test

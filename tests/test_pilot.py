@@ -222,3 +222,30 @@ def test_jaxmesh_overallocation_rejected():
     with pytest.raises(RuntimeError, match="devices"):
         pcs.submit_pilot(PilotDescription(
             resource="jax://mesh", attrs={"mesh_shape": (1000,), "mesh_axes": ("data",)}))
+
+
+class _LateDeviceError:
+    """An output whose error surfaces only when it is waited on, as an
+    error on an asynchronously dispatched device computation does."""
+
+    def block_until_ready(self):
+        raise RuntimeError("RESOURCE_EXHAUSTED: out of memory on the device")
+
+
+@pytest.mark.parametrize("fails", ["in_call", "on_device"])
+def test_jaxmesh_failed_unit_carries_its_exception(fails):
+    pcs = make_service()
+    pilot = pcs.submit_pilot(PilotDescription(
+        resource="jax://mesh", attrs={"mesh_shape": (1,), "mesh_axes": ("data",)}))
+
+    def fn():
+        if fails == "in_call":
+            raise ValueError("bad input")
+        return _LateDeviceError()
+
+    cu = pilot.submit_compute_unit(func=fn)
+    assert cu.state == State.FAILED
+    expected = ValueError if fails == "in_call" else RuntimeError
+    assert isinstance(cu.exception, expected)
+    with pytest.raises(expected):
+        cu.result(timeout=30)

@@ -13,12 +13,13 @@ from tests.test_distributed import run_with_devices
 def test_decode_kv_policy_matches_plain_decode():
     out = run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.configs.base import get_config, pad_for_mesh, ShapeSpec
         from repro.launch.steps import build_cell
         from repro.models import model as M
         arch = "qwen2.5-14b"
         cfg0 = get_config(arch, reduced=True)          # 5 heads, kv=1
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         shape = ShapeSpec("d", 64, 8, "decode")
         # padded config the policy will use (pad_kv aligns groups)
         cfgp = pad_for_mesh(cfg0, 4, pad_kv=True)
@@ -45,13 +46,14 @@ def test_decode_kv_policy_matches_plain_decode():
 def test_fsdp_policy_matches_plain_train_loss():
     out = run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.configs.base import get_config, pad_for_mesh, ShapeSpec
         from repro.launch.steps import build_cell
         from repro.models import model as M
         from repro.training.optimizer import init_opt_state
         arch = "qwen2-0.5b"
         cfg0 = get_config(arch, reduced=True)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         shape = ShapeSpec("t", 64, 8, "train")
         cfgp = pad_for_mesh(cfg0, 4)
         params = M.init_params(jax.random.PRNGKey(0), cfgp)
@@ -74,11 +76,12 @@ def test_moe_a2a_dispatch_matches_local():
     """All-to-all expert dispatch == single-device oracle (ample capacity)."""
     out = run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.configs.base import get_config, pad_for_mesh
         from repro.distributed.sharding import make_moe_a2a_rules, use_rules
         from repro.models import moe as moe_mod
         cfg = pad_for_mesh(get_config("qwen3-moe-235b-a22b", reduced=True), 4)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         rules = make_moe_a2a_rules(False); rules.mesh = mesh
         p = moe_mod.moe_init(jax.random.PRNGKey(0), cfg, jnp.float32)
         x = 0.5 * jax.random.normal(jax.random.PRNGKey(1), (2, 16, cfg.d_model))

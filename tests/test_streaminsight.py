@@ -43,6 +43,31 @@ def test_run_cells_preserves_input_order():
     assert [r.experiment.partitions for r in results] == [4, 1, 2]
 
 
+def test_pool_workers_are_pinned_to_the_cpu(monkeypatch):
+    """Pool workers simulate on the host and never start an accelerator
+    backend: the chip stays with the parent process that drives it."""
+    import os
+
+    import jax
+
+    import repro.core.streaminsight as si
+
+    platforms = jax.config.jax_platforms
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    try:
+        si._pin_worker_to_cpu()
+        assert os.environ["JAX_PLATFORMS"] == "cpu"
+        assert jax.config.jax_platforms == "cpu"
+    finally:
+        jax.config.update("jax_platforms", platforms)
+    si._reset_pool()
+    try:
+        worker_env = si._get_pool(1).submit(os.getenv, "JAX_PLATFORMS")
+        assert worker_env.result(timeout=120) == "cpu"
+    finally:
+        si._reset_pool()
+
+
 def test_auto_switch_runs_cheap_grids_serially(monkeypatch):
     """parallel=True on a cheap grid must not touch the process pool."""
     import repro.core.streaminsight as si
