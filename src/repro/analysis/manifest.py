@@ -177,8 +177,8 @@ DEFAULT_MANIFEST = Manifest(
         # its ticker live on the wall clock by design
         ("*/repro/streaming/engine.py", "ThreadedStreamingEngine*", "wall"),
         ("*/repro/streaming/engine.py", "_WallTicker*", "wall"),
-        # Timer is the wall-clock duration context manager
-        ("*/repro/core/metrics.py", "Timer*", "wall"),
+        # span is the wall-clock duration instrument (profiler trace spans)
+        ("*/repro/core/metrics.py", "span", "wall"),
         # miniapp's wall-clock adaptation path (threaded producer + runner)
         ("*/repro/core/miniapp.py", "_WallClockProducer*", "wall"),
         ("*/repro/core/miniapp.py", "_run_adaptation_threaded*", "wall"),

@@ -20,10 +20,17 @@ Pooled experiment sweeps run in worker processes with private registries;
 ``export_summary`` / ``merge_summary`` are the compact return channel that
 carries per-(component, kind) event summaries back into the caller's
 registry (see ``streaminsight.run_cells``).
+
+``span`` is the layer's wall-clock duration instrument for the real
+(threaded, on-device) path: a named span written into the JAX profiler's
+trace, on the device trace's clock, while a profiler session records, and
+a shared no-op otherwise.  The virtual-clock simulators record no spans.
 """
 
 from __future__ import annotations
 
+import contextlib
+import functools
 import itertools
 import sys
 import threading
@@ -33,7 +40,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
-__all__ = ["new_run_id", "TraceEvent", "MetricRegistry", "Timer", "percentile_summary"]
+__all__ = ["new_run_id", "TraceEvent", "MetricRegistry", "span", "percentile_summary"]
 
 _counter = itertools.count()
 
@@ -263,25 +270,25 @@ class MetricRegistry:
         return sorted({key[0] for key in self._cols} | merged)
 
 
-class Timer:
-    """Context manager recording wall-clock duration into a registry series."""
+_NO_SPAN = contextlib.nullcontext()
 
-    def __init__(self, registry: MetricRegistry, name: str, clock=None) -> None:
-        import time
 
-        self.registry = registry
-        self.name = name
-        self.clock = clock or time.perf_counter
-        self.elapsed = 0.0
+@functools.cache
+def _trace_annotation():
+    # jax is imported on the first span asked for, never by the simulators
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation
 
-    def __enter__(self):
-        self._t0 = self.clock()
-        return self
 
-    def __exit__(self, *exc):
-        self.elapsed = self.clock() - self._t0
-        self.registry.observe(self.name, self._t0, self.elapsed)
-        return False
+def span(name: str, **meta):
+    """A span ``name`` in the profiler's trace, carrying ``meta`` as its
+    metadata, while a profiler session records; otherwise one shared no-op
+    context manager, so an unrecorded span allocates nothing and formats
+    no metadata."""
+    annotation = _trace_annotation()
+    if annotation.is_enabled():
+        return annotation(name, **meta)
+    return _NO_SPAN
 
 
 def percentile_summary(values) -> dict:

@@ -19,6 +19,7 @@ import time
 import jax
 from jax.sharding import Mesh
 
+from repro.core.metrics import span
 from repro.pilot.api import Backend, ComputeUnit, Pilot, State, register_backend
 
 
@@ -61,19 +62,23 @@ class JaxMeshBackend(Backend):
 
     # -- execution: run under the pilot's mesh ---------------------------------
     def submit(self, pilot: Pilot, cu: ComputeUnit) -> None:
-        cu.submit_ts = time.perf_counter()
-        cu._set_running(time.perf_counter())
-        try:
-            with pilot.mesh:
-                out = cu.desc.func(*cu.desc.args, **cu.desc.kwargs) if cu.desc.func else None
-                # dispatch is asynchronous: wait for the device, so that a
-                # device error fails this unit and end_ts is the device's end
-                jax.block_until_ready(out)
-            cu._set_done(time.perf_counter(), out)
-        except BaseException as exc:  # noqa: BLE001
-            cu._set_failed(time.perf_counter(), exc)
-        with self._cv:
-            self._cv.notify_all()
+        with span("pilot.unit", partition=cu.desc.partition, unit=cu.uid):
+            cu.submit_ts = time.perf_counter()
+            cu._set_running(time.perf_counter())
+            try:
+                with pilot.mesh:
+                    with span("pilot.fn", unit=cu.uid):
+                        out = (cu.desc.func(*cu.desc.args, **cu.desc.kwargs)
+                               if cu.desc.func else None)
+                    # dispatch is asynchronous: wait for the device, so that a
+                    # device error fails this unit and end_ts is the device's end
+                    with span("pilot.block", unit=cu.uid):
+                        jax.block_until_ready(out)
+                cu._set_done(time.perf_counter(), out)
+            except BaseException as exc:  # noqa: BLE001
+                cu._set_failed(time.perf_counter(), exc)
+            with self._cv:
+                self._cv.notify_all()
 
     def drive_until(self, predicate, timeout) -> None:
         deadline = None if timeout is None else time.perf_counter() + timeout

@@ -68,7 +68,7 @@ from typing import Any, Callable
 
 import numpy as np
 
-from repro.core.metrics import MetricRegistry
+from repro.core.metrics import MetricRegistry, span
 from repro.pilot.api import ComputeUnitDescription, Pilot, State, TaskProfile
 from repro.sim.des import Simulator
 from repro.streaming.broker import Broker, Message
@@ -691,13 +691,16 @@ class ThreadedStreamingEngine:
                 self._stop.wait(min(pause, self.poll_interval))
                 continue
             wakeup.clear()
-            msgs = core.broker.fetch(core.topic, partition, ps.next_offset, core.batch_max)
+            with span("engine.fetch", partition=partition):
+                msgs = core.broker.fetch(core.topic, partition, ps.next_offset,
+                                         core.batch_max)
             if not msgs:
                 with core.counter_lock:
                     core.idle_fetches += 1
                 # an append between the fetch and this wait sets the event,
                 # so the wait returns immediately — no lost wakeups
-                wakeup.wait(self.poll_interval)
+                with span("engine.wait", partition=partition):
+                    wakeup.wait(self.poll_interval)
                 continue
             attempts = 0
             while True:
@@ -707,8 +710,10 @@ class ThreadedStreamingEngine:
                     return
                 if winner.state == State.DONE:
                     now = time_mod.perf_counter()
-                    if core.on_batch_done(partition, msgs, now):
-                        core.completed_runtimes.append(winner.runtime)
+                    with span("engine.commit", partition=partition,
+                              offset=msgs[0].offset):
+                        if core.on_batch_done(partition, msgs, now):
+                            core.completed_runtimes.append(winner.runtime)
                     if loser is not None:
                         # first-finisher-wins: the losing copy must settle
                         # on the idempotent duplicate path when it lands
