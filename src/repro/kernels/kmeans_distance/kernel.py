@@ -9,11 +9,12 @@ in VMEM and each grid step emits one (block_n × block_c) output tile.
 Two kernels:
 
 * ``pairwise_sq_dists_pallas`` — materializes the (n, c) distance matrix.
-* ``assign_pallas`` — fused distances + running argmin over centroid blocks:
-  the grid's trailing dimension walks centroid panels while the output
-  (labels, best) block stays resident in VMEM, so the (n, c) matrix is never
-  written to HBM — an O(c/d)× HBM-write saving over kernel 1 for the
-  assignment use-case (the K-Means inner loop only needs argmin).
+* ``assign_pallas`` — fused distances + running argmin over centroid blocks,
+  the K-Means step's path: the grid's trailing dimension walks centroid
+  panels while per-lane running minima stay in VMEM scratch, so the (n, c)
+  matrix is never written to HBM (the K-Means inner loop only needs the
+  argmin), and a tile costs elementwise compares rather than cross-lane
+  reductions.
 
 Feature dim d is zero-padded to the 128-lane boundary by ``ops.py``;
 zero padding does not change distances (contributes 0 to every norm/dot).
@@ -29,9 +30,16 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BLOCK_N = 256
 DEFAULT_BLOCK_C = 256
+# the fused assignment's largest blocks (``ops.assign_blocks``); on one
+# v5e at 26,000 x 8,192, 256 x 2,048 ran in 1.85 ms, 512 x 1,024 in 1.92,
+# 256 x 256 in 2.28
+ASSIGN_BLOCK_N = 256
+ASSIGN_BLOCK_C = 2048
+LANES = 128
 
 
 def _dist_tile(x_blk, c_blk):
@@ -83,22 +91,40 @@ def pairwise_sq_dists_pallas(x: jax.Array, c: jax.Array, *,
 # Kernel 2: fused assignment (distances + running argmin, no HBM matrix)
 # --------------------------------------------------------------------------
 
-def _assign_kernel(x_ref, c_ref, labels_ref, best_ref):
+def _assign_kernel(x_ref, c_ref, labels_ref, best_ref, run_min, run_idx):
+    """One (block_n, block_c) tile.  ``run_min``/``run_idx`` hold, per row
+    and per lane, the least distance seen so far in that lane and the
+    lowest centroid index that reached it: a tile costs only elementwise
+    compares and selects.  The one cross-lane reduction runs on the last
+    centroid block."""
     j = pl.program_id(1)
+    bc = c_ref.shape[0]
 
     @pl.when(j == 0)
     def _init():
-        best_ref[...] = jnp.full_like(best_ref, jnp.inf)
-        labels_ref[...] = jnp.zeros_like(labels_ref)
+        run_min[...] = jnp.full_like(run_min, jnp.inf)
+        run_idx[...] = jnp.zeros_like(run_idx)
 
     d2 = _dist_tile(x_ref[...], c_ref[...])                  # (bn, bc)
-    blk_best = jnp.min(d2, axis=1, keepdims=True)            # (bn, 1)
-    blk_arg = jnp.argmin(d2, axis=1, keepdims=True).astype(jnp.int32)
-    bc = d2.shape[1]
-    cur_best = best_ref[...]
-    take = blk_best < cur_best
-    best_ref[...] = jnp.where(take, blk_best, cur_best)
-    labels_ref[...] = jnp.where(take, blk_arg + j * bc, labels_ref[...])
+    lane = jax.lax.broadcasted_iota(jnp.int32, run_idx.shape, 1)
+    best, idx = run_min[...], run_idx[...]
+    for q in range(bc // LANES):
+        # strict <: a lane keeps the lowest index among equal distances,
+        # since its indices grow with q and j
+        v = d2[:, q * LANES:(q + 1) * LANES]
+        take = v < best
+        best = jnp.where(take, v, best)
+        idx = jnp.where(take, lane + (j * bc + q * LANES), idx)
+    run_min[...] = best
+    run_idx[...] = idx
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _finish():
+        row_min = jnp.min(best, axis=1, keepdims=True)
+        # the lowest index among the lanes that hold the row's minimum
+        cand = jnp.where(best == row_min, idx, jnp.iinfo(jnp.int32).max)
+        labels_ref[...] = jnp.min(cand, axis=1, keepdims=True)
+        best_ref[...] = row_min
 
 
 @partial(jax.jit, static_argnames=("block_n", "block_c", "interpret"))
@@ -107,8 +133,10 @@ def assign_pallas(x: jax.Array, c: jax.Array, *,
                   block_c: int = DEFAULT_BLOCK_C,
                   interpret: bool = False):
     """Fused K-Means assignment: returns (labels (n, 1) int32, best (n, 1)
-    f32).  The outputs are 2-D because Mosaic refuses 1-D ``(block_n,)``
-    output blocks: their XLA and Mosaic tilings differ."""
+    f32), the lowest index among equal distances.  n % block_n == 0,
+    k % block_c == block_c % 128 == 0 and d % 128 == 0 (``ops.py`` pads).
+    The outputs are 2-D because Mosaic refuses 1-D ``(block_n,)`` output
+    blocks: their XLA and Mosaic tilings differ."""
     n, d = x.shape
     k, _ = c.shape
     grid = (n // block_n, k // block_c)   # trailing axis: centroid panels
@@ -127,6 +155,8 @@ def assign_pallas(x: jax.Array, c: jax.Array, *,
             jax.ShapeDtypeStruct((n, 1), jnp.int32),
             jax.ShapeDtypeStruct((n, 1), jnp.float32),
         ],
+        scratch_shapes=[pltpu.VMEM((block_n, LANES), jnp.float32),
+                        pltpu.VMEM((block_n, LANES), jnp.int32)],
         interpret=interpret,
     )(x, c)
     return labels, best

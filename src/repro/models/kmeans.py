@@ -1,11 +1,12 @@
 """MiniBatch K-Means in JAX — the paper's representative streaming workload.
 
-K-Means has complexity O(n·c): phase 1 computes Euclidean distances between
-all n points and c centroids (the compute hot-spot, implemented as the
-``kmeans_distance`` Pallas kernel on TPU with a jnp fallback elsewhere);
-phase 2 updates centroid positions with the MiniBatch rule (Sculley 2010 /
-sklearn MiniBatchKMeans): per-centroid counts give a decaying learning rate
-``eta = m_batch / count`` so centroids converge as streams arrive.
+K-Means has complexity O(n·c): phase 1 assigns each of n points to its
+nearest of c centroids, the compute hot-spot (on TPU the ``kmeans_distance``
+fused distance + argmin Pallas kernel, which never writes the (n, c)
+distances to HBM; a jnp fallback elsewhere); phase 2 updates centroid
+positions with the MiniBatch rule (Sculley 2010 / sklearn MiniBatchKMeans):
+per-centroid counts give a decaying learning rate ``eta = m_batch / count``
+so centroids converge as streams arrive.
 
 The model state (centroids, counts) is what the paper shares across tasks
 via file storage (S3 / Lustre) — see ``core.miniapp`` for how the sharing
@@ -33,20 +34,13 @@ def init_state(key: jax.Array, n_centroids: int, dim: int, scale: float = 1.0) -
     return KMeansState(centroids=centroids, counts=jnp.zeros((n_centroids,), jnp.float32))
 
 
-def _pairwise_sq_dists(points: jax.Array, centroids: jax.Array) -> jax.Array:
-    """(n, c) squared Euclidean distances via the matmul formulation
-    ||x||^2 + ||c||^2 - 2 x.c^T — the MXU-friendly form the Pallas kernel tiles."""
+def assign(points: jax.Array, centroids: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """Returns (labels (n,), sq_dist_to_assigned (n,)), the lowest index
+    among equal distances, from the fused kernel: the (n, c) distance
+    matrix is never materialised."""
     from repro.kernels.kmeans_distance import ops as kd_ops
 
-    return kd_ops.pairwise_sq_dists(points, centroids)
-
-
-def assign(points: jax.Array, centroids: jax.Array) -> tuple[jax.Array, jax.Array]:
-    """Returns (labels (n,), sq_dist_to_assigned (n,))."""
-    d2 = _pairwise_sq_dists(points, centroids)
-    labels = jnp.argmin(d2, axis=1)
-    best = jnp.min(d2, axis=1)
-    return labels, best
+    return kd_ops.assign(points, centroids)
 
 
 @partial(jax.jit, donate_argnums=(0,))

@@ -47,6 +47,35 @@ def test_kmeans_assign_matches_ref(n, k, d):
         rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("n,k,c_blocks,dups", [
+    (300, 1000, 1, ()),               # n not a multiple of block_n
+    (256, 128, 1, ()),                # c of one block
+    (256, 4608, 3, ()),               # c of several blocks
+    (256, 4500, 3, ()),               # c padded with sentinels
+    (256, 4608, 3, (1541, 3100)),     # copies of centroid 5 in later blocks
+], ids=["ragged-n", "one-block", "three-blocks", "sentinels", "ties"])
+def test_kmeans_assign_labels_equal_argmin_of_dists(n, k, c_blocks, dups):
+    """At the model's own block sizes, the fused kernel's labels are the
+    argmin of the distance matrix, lowest index first at ties."""
+    bn, bc = kd_ops.assign_blocks(n, k)
+    assert (n % bn != 0) == (n == 300)
+    assert -(-k // bc) == c_blocks and (k % bc != 0) == (k in (1000, 4500))
+    kx, kc = jax.random.split(KEY)
+    x = jax.random.normal(kx, (n, 9), jnp.float32)
+    c = jax.random.normal(kc, (k, 9), jnp.float32)
+    if dups:
+        c = c.at[jnp.asarray(dups)].set(c[5])
+        x = x.at[:32].set(c[5] + 0.01 * x[:32])
+    labels, best = kd_ops.assign(x, c, use_pallas=True, interpret=True)
+    d2 = np.asarray(kd_ops.pairwise_sq_dists(x, c, use_pallas=True,
+                                             interpret=True))
+    np.testing.assert_array_equal(np.asarray(labels), np.argmin(d2, axis=1))
+    np.testing.assert_allclose(np.asarray(best), d2.min(axis=1),
+                               rtol=1e-6, atol=1e-6)
+    if dups:
+        assert (np.asarray(labels)[:32] == 5).all()
+
+
 # -- flash_attention -----------------------------------------------------------
 
 @pytest.mark.parametrize("bh,bkv,s,dh", [(4, 4, 128, 64), (8, 2, 256, 64),

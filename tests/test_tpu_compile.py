@@ -71,8 +71,9 @@ def test_fused_assign_compiles_for_v5e(one_chip):
 
 def test_minibatch_step_compiles_for_v5e_and_fits(one_chip, monkeypatch):
     """The whole model update, as the chip runs it: the kernel is on the
-    path and one step fits the chip's HBM.  ``ops`` picks the kernel from
-    the default backend, which is the CPU here, so the test steers it."""
+    path, one step fits the chip's HBM, and its temporaries hold no (n, c)
+    distance matrix.  ``ops`` picks the kernel from the default backend,
+    which is the CPU here, so the test steers it."""
     monkeypatch.setattr(kd_ops, "_on_tpu", lambda: True)
     n, c = LARGE
     state = kmeans.KMeansState(centroids=_sds((c, DIM), one_chip),
@@ -83,3 +84,4 @@ def test_minibatch_step_compiles_for_v5e_and_fits(one_chip, monkeypatch):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert used < V5E_HBM_BYTES
+    assert mem.temp_size_in_bytes < n * c * 4
