@@ -1,7 +1,8 @@
-"""On-chip benchmark of the streaming K-Means mini-app.
+"""On-chip benchmark of the streaming mini-app's served path.
 
 ``python bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>``
 runs one cell of ``BENCHMARK.json`` on the chip it is started on.
-Configurations, traffic mixes and metric readers are files found by name
-under ``bench/configs``, ``bench/traffic`` and ``bench/metrics``.
+Configurations, traffic mixes, metric readers and the models the
+configurations name are files found by name under ``bench/configs``,
+``bench/traffic``, ``bench/metrics`` and ``bench/apps``.
 """

@@ -5,7 +5,7 @@
         --seeds 1,2,3 --control-seeds 4,5,6
 
 Runs the cell in one process, once per seed with the program's step and
-once per control seed with ``bench/control.py``'s step (the reference at
+once per control seed with the app's ``control`` step (the reference at
 the precision just below the configuration's) in its place, each through
 the whole served path at the cell's own load, and prints one JSON line
 per run: the seed, which step ran, and each number the check compares.
@@ -37,7 +37,6 @@ def main(argv=None) -> int:
     import jax
 
     from bench import harness
-    from bench.control import control_step
     from repro.launch.compile_cache import use_compile_cache
 
     if jax.devices()[0].platform != "tpu":
@@ -47,8 +46,9 @@ def main(argv=None) -> int:
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     harness.CompileCounter.install()
     bench = harness.Bench(ROOT)
+    app = bench.app(bench.config(bench.cell(args.workload)["config"])["app"])
     runs = [(int(s), "program", None) for s in args.seeds.split(",") if s]
-    runs += [(int(s), "control", control_step)
+    runs += [(int(s), "control", app.control)
              for s in args.control_seeds.split(",") if s]
     t0 = T_START
     for seed, kind, step in runs:
@@ -59,7 +59,7 @@ def main(argv=None) -> int:
                           "step": kind, "correct": line["correct"],
                           "metrics": {k: v["value"] for k, v in
                                       line["metrics"].items()},
-                          "near_ties": run.extra["near_ties"],
+                          "notes": run.extra["notes"],
                           "checks": run.extra["checks"]}), flush=True)
         t0 = time.perf_counter()
     return 0

@@ -5,20 +5,45 @@ line.
 The served path is the paper's streaming mini-app (arXiv 1909.06055 §IV):
 the benchmark's open-loop generator appends messages to a partitioned
 ``Broker`` topic; a ``ThreadedStreamingEngine`` hands each one to a
-``jax://`` pilot, where the mini-app's plain user function converts the
-message with ``jnp.asarray`` and applies ``kmeans.minibatch_step`` to the
-shared model under the model lock (``full_fit_locked`` sharing).  The
-function returns nothing: the model is its only output.
+``jax://`` pilot, where a plain user function converts the message, takes
+the model lock if the step runs under it, and applies the model's step.
 
 Everything that belongs to one configuration, traffic mix or metric is a
 file found by its name: ``BENCHMARK.json`` names the configuration's file,
-``bench/traffic/<name>.json`` holds a mix and ``bench/metrics/<name>.py``
-a metric's reader, ``read(run) -> number or None``.
+``bench/traffic/<name>.json`` holds a mix, ``bench/metrics/<name>.py`` a
+metric's reader, ``read(run) -> number or None``, and the configuration's
+``"app"`` names ``bench/apps/<app>.py``, which holds all the harness knows
+of one model:
+
+``make_pool(cfg, seed)``, ``size_bytes(x)``
+    the seeded payloads (message ``i`` carries ``i % len(pool)``) and the
+    size in bytes the broker records for one;
+``init_state(cfg, key, device)``, ``warm_up(cfg, key, device, pool, step)``
+    the model's state on the device, and the warm-up that compiles every
+    shape the timed path runs, under the pilot's mesh, and returns a
+    fresh state;
+``convert(x)``
+    the host-to-device conversion of one payload (``bench.convert``);
+``make_step(cfg, program=None)``
+    ``(step, locked)``: the timed step ``(state, x) -> (state, out)``
+    around the program's own step or a stand-in for it, and whether it
+    runs under the model lock (the configuration's sharing policy);
+``capture(state, out)``
+    what the check keeps of a checked step, copied on the device: before
+    the step (``out`` None) and after it;
+``check(cfg, key, x, before, after)``
+    after the window, one checked step against the app's reference:
+    numbers by name, each compared with ``cfg["limits"][name]``, and the
+    rest logged as notes, summed over the checked steps;
+``STEP_MODULE``, ``KERNEL_NAMES``, ``control``
+    the step's and its kernels' names in the trace, and the control
+    step that ``bench/calibrate.py`` puts in the program's place.
 """
 
 from __future__ import annotations
 
 import importlib.util
+import itertools
 import json
 import shutil
 import threading
@@ -28,22 +53,18 @@ from dataclasses import dataclass, field
 from pathlib import Path
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
-from bench import reference, traffic
+from bench import traffic
 from bench.peaks import peaks_for
 from bench.trace import reduce_trace
 
 __all__ = ["Bench", "Run", "run_cell", "result_line", "CompileCounter"]
 
 ROOT = Path(__file__).resolve().parents[1]
-TOPIC, GROUP = "points", "engine"
+TOPIC, GROUP = "messages", "engine"
 TRACE_SECONDS = 2.0           # traced part of a --trace 1 window
 TRACE_DIR = ".bench_trace"    # inside the checkout, emptied after reading
-STEP_MODULE = "minibatch_step"
-# the kmeans_distance kernels' operations, as the trace names them
-KERNEL_NAMES = ("pairwise_sq_dists_pallas", "assign_pallas")
 clock = time.perf_counter
 
 
@@ -80,12 +101,21 @@ class Bench:
                 if cell in m.get("workloads", [cell])]
 
     def reader(self, metric: str):
-        path = self.root / "bench" / "metrics" / f"{metric}.py"
-        spec = importlib.util.spec_from_file_location(
-            f"bench_metric_{metric.replace('.', '_')}", path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod.read
+        return _load(self.root / "bench" / "metrics" / f"{metric}.py",
+                     "bench_metric").read
+
+    def app(self, name: str):
+        """The module ``bench/apps/<name>.py``: one model, as the harness
+        drives it (the module docstring lists what it holds)."""
+        return _load(self.root / "bench" / "apps" / f"{name}.py", "bench_app")
+
+
+def _load(path: Path, prefix: str):
+    name = f"{prefix}_{path.stem.replace('.', '_').replace('-', '_')}"
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 class CompileCounter:
@@ -149,10 +179,10 @@ class Run:
 
 def _check_positions(cfg: dict, mix: dict, sched: traffic.Schedule,
                      seconds: float, seed: int) -> set:
-    """Places in the lock's order at which the timed path copies the model
-    before and after its step, for the check: the window's first
-    ``check_first`` steps, where the model is young and each step moves it
-    most, and ``check_steps`` more drawn from the seed among those a run
+    """Places in the order the steps start (the lock's, for a step under
+    it) at which the timed path captures a step for the check: the
+    window's first ``check_first`` steps, where a young model moves most,
+    and ``check_steps`` more drawn from the seed among those a run
     surely reaches: of a backlog, the mix's ``reach_per_s`` × ``seconds``
     (below what the system drains), and nine tenths of the arrivals."""
     queued = min(sched.backlog, int(mix["reach_per_s"] * seconds)) \
@@ -164,11 +194,6 @@ def _check_positions(cfg: dict, mix: dict, sched: traffic.Schedule,
     return first | set(int(p) for p in rng.choice(reach, size=k, replace=False))
 
 
-@jax.jit
-def _copy_state(state):
-    return jax.tree.map(jnp.copy, state)
-
-
 def _span(name: str):
     return jax.profiler.TraceAnnotation(name)
 
@@ -177,37 +202,43 @@ def _no_span(_name: str):
     return nullcontext()
 
 
+class _NoLock:
+    """The model lock of a step that does not run under one."""
+
+    def acquire(self) -> bool:
+        return True
+
+    def release(self) -> None:
+        pass
+
+
 def run_cell(bench: Bench, name: str, seed: int, seconds: float, trace: bool,
              *, t_start: float, step=None, log=None) -> Run:
     """One run of cell ``name``: set-up, the window, the check.  ``step``
     replaces the program's step (the control, or a planted fault)."""
     from repro.core.metrics import MetricRegistry
-    from repro.models import kmeans
     from repro.pilot.api import PilotComputeService, PilotDescription, State
     from repro.streaming.broker import Broker
     from repro.streaming.engine import ThreadedStreamingEngine, Workload
 
     log = log or (lambda *_: None)
-    step = step or kmeans.minibatch_step
     cell = bench.cell(name)
     cfg = bench.config(cell["config"])
     mix = bench.traffic(cell["traffic"])
-    dim, k = cfg["dim"], cfg["centroids"]
-    if cfg["sharing"] != "full_fit_locked":
-        raise ValueError(f"sharing {cfg['sharing']!r}: only full_fit_locked "
-                         f"is driven by this harness")
+    app = bench.app(cfg["app"])
+    step, locked = app.make_step(cfg, step)
+    convert, capture = app.convert, app.capture
     device = jax.devices()[0]
     peaks = peaks_for(device.device_kind) if device.platform == "tpu" else {}
 
     t_data = clock()
     sched = traffic.schedule(mix, seconds, seed)
-    pool = traffic.make_pool(cfg, seed)
+    pool = app.make_pool(cfg, seed)
+    sizes = [app.size_bytes(x) for x in pool]
     n_msgs = sched.backlog + len(sched.due)
     positions = _check_positions(cfg, mix, sched, seconds, seed)
     key = jax.random.PRNGKey(int(traffic.seed_stream(
         seed, traffic.STREAM_MODEL).generate_state(1)[0]))
-    init = jax.jit(kmeans.init_state, static_argnums=(1, 2, 3))
-    scale = cfg["data"]["init_scale"]
 
     t_warm = clock()
     pcs = PilotComputeService()
@@ -215,32 +246,26 @@ def run_cell(bench: Bench, name: str, seed: int, seconds: float, trace: bool,
         resource="jax://mesh", partitions=cfg["partitions"],
         attrs={"mesh_shape": (cell["chips"],), "mesh_axes": ("data",)}))
     with pilot.mesh:   # units run under the pilot's mesh: warm up there
-        warm = jax.device_put(init(key, k, dim, scale), device)
-        pts0 = jnp.asarray(pool[0])
-        for _ in range(2):    # a fresh state, then a step's own output
-            _copy_state(warm)
-            warm = step(warm, pts0)
-        jax.block_until_ready(warm)
-        state = jax.device_put(init(key, k, dim, scale), device)
-        jax.block_until_ready(state)
-    del warm, pts0
+        state = app.warm_up(cfg, key, device, pool, step)
 
     t_engine = clock()
     broker = Broker()
     broker.create_topic(TOPIC, cfg["partitions"])
     registry = MetricRegistry()
     run_id = f"{name}-{seed}"
-    lock = threading.Lock()
-    applied: list[int] = []           # message numbers, in the lock's order
-    snaps: dict[int, tuple] = {}      # lock position -> (msg, before, after)
+    lock = threading.Lock() if locked else _NoLock()
+    order = itertools.count()         # steps started, in the lock's order
+    applied: list[int] = []           # message numbers, as steps ended
+    snaps: dict[int, tuple] = {}      # position -> (msg, before, after)
     stamps = np.full((n_msgs, 5), np.nan)
     where = np.full((n_msgs, 2), -1, np.int64)    # partition, offset
     appended = np.full(n_msgs, np.nan)
     span = _span if trace else _no_span
 
     def process(msgs):
-        """The mini-app's user function: convert, then update the shared
-        model under the lock.  Returns nothing held on the device."""
+        """The mini-app's user function: convert, then step the model,
+        under the lock where the step runs under it.  Returns nothing held
+        on the device."""
         nonlocal state
         for m in msgs:
             i = int(m.msg_id)
@@ -248,47 +273,48 @@ def run_cell(bench: Bench, name: str, seed: int, seconds: float, trace: bool,
             where[i] = m.partition, m.offset
             t[0] = clock()
             with span("bench.convert"):
-                pts = jnp.asarray(m.value)
+                x = convert(m.value)
             t[1] = clock()
             with span("bench.lock_wait"):
                 lock.acquire()
             try:
-                pos = len(applied)
-                before = _copy_state(state) if pos in positions else None
+                pos = next(order)
+                checked = pos in positions
+                before = capture(state, None) if checked else None
                 t[2] = clock()
                 with span("bench.dispatch"):
-                    state = step(state, pts)
+                    state, out = step(state, x)
                 t[3] = clock()
-                after = _copy_state(state) if before is not None else None
+                after = capture(state, out) if checked else None
                 with span("bench.block"):
-                    jax.block_until_ready(state)
+                    jax.block_until_ready((state, out))
                 t[4] = clock()
                 applied.append(i)
-                if before is not None:
+                if checked:
                     snaps[pos] = (i, before, after)
             finally:
                 lock.release()
 
     engine = ThreadedStreamingEngine(
-        broker, TOPIC, pilot, Workload(fn=process, name="kmeans"), registry,
+        broker, TOPIC, pilot, Workload(fn=process, name=cfg["app"]), registry,
         run_id, group=GROUP, batch_max=cfg["batch_max"], max_retries=0)
     for i in range(sched.backlog):
-        broker.append(TOPIC, pool[i % len(pool)], ts=clock(), run_id=run_id,
-                      msg_id=str(i), size_bytes=pool[0].nbytes)
+        j = i % len(pool)
+        broker.append(TOPIC, pool[j], ts=clock(), run_id=run_id,
+                      msg_id=str(i), size_bytes=sizes[j])
     stop = threading.Event()
     due = np.full(n_msgs, np.nan)
 
     def produce() -> None:
-        for j in range(len(sched.due)):
-            i = sched.backlog + j
+        for i in range(sched.backlog, n_msgs):
             while (now := clock()) < due[i]:
                 if stop.wait(due[i] - now):
                     return
             appended[i] = now
+            j = i % len(pool)
             with span("bench.append"):
-                broker.append(TOPIC, pool[i % len(pool)], ts=now,
-                              run_id=run_id, msg_id=str(i),
-                              size_bytes=pool[0].nbytes)
+                broker.append(TOPIC, pool[j], ts=now, run_id=run_id,
+                              msg_id=str(i), size_bytes=sizes[j])
 
     producer = threading.Thread(target=produce, name="bench-producer")
     trace_dir = bench.root / TRACE_DIR
@@ -336,7 +362,7 @@ def run_cell(bench: Bench, name: str, seed: int, seconds: float, trace: bool,
     for cu in failed_units[:3]:
         log(f"failed unit: {cu.exception!r}")
 
-    # -- the check: delivery, then each copied step against the reference
+    # -- the check: delivery, then each captured step against the reference
     counted = np.flatnonzero(committed < t_close)
     times = np.bincount(np.asarray(applied, np.int64), minlength=n_msgs)
     commits = [broker.committed(GROUP, TOPIC, p)
@@ -349,20 +375,16 @@ def run_cell(bench: Bench, name: str, seed: int, seconds: float, trace: bool,
     steps = []
     for pos in sorted(snaps):
         i, before, after = snaps.pop(pos)
-        host = [tuple(np.asarray(a) for a in (s.centroids, s.counts))
-                for s in (before, after)]
-        steps.append(reference.check_step(pool[i % len(pool)], *host))
+        steps.append(app.check(cfg, key, pool[i % len(pool)], before, after))
     state = None      # the model's last device buffers go with the pilot
     limits = cfg["limits"]
-    checks = {
-        "count_err": [max((s["count_err"] for s in steps), default=0.0),
-                      limits["count_err"]],
-        "centroid_err": [max((s["centroid_err"] for s in steps), default=0.0),
-                         limits["centroid_err"]],
-        "delivery_err": [delivery_err, 0],
-        "compiles_in_window": [compiles_in_window, 0],
-        "steps_checked": [len(steps), 1],
-    }
+    checks = {name: [max((s[name] for s in steps), default=0.0), limit]
+              for name, limit in limits.items()}
+    checks.update(delivery_err=[delivery_err, 0],
+                  compiles_in_window=[compiles_in_window, 0],
+                  steps_checked=[len(steps), 1])
+    notes = {name: sum(s[name] for s in steps)
+             for name in (steps[0] if steps else {}) if name not in limits}
     correct = (all(v <= lim for key, (v, lim) in checks.items()
                    if key != "steps_checked")
                and checks["steps_checked"][0] >= checks["steps_checked"][1])
@@ -374,13 +396,13 @@ def run_cell(bench: Bench, name: str, seed: int, seconds: float, trace: bool,
     if trace:
         files = sorted(trace_dir.glob("plugins/profile/*/*.xplane.pb"))
         run.trace = reduce_trace(str(files[-1]), chips=cell["chips"],
-                                 step_module=STEP_MODULE,
-                                 kernel_names=KERNEL_NAMES)
+                                 step_module=app.STEP_MODULE,
+                                 kernel_names=app.KERNEL_NAMES)
         shutil.rmtree(trace_dir, ignore_errors=True)
     run.extra = {
         "correct": bool(correct),
         "checks": checks,
-        "near_ties": sum(s["near_ties"] for s in steps),
+        "notes": notes,
         "applied": len(applied),
         "failed": len(failed_units) + core.abandoned,
         # a program's temporaries live in the allocator's reserved

@@ -69,8 +69,8 @@ def main(argv=None) -> int:
             "capacity; no result")
         return 3
     line = harness.result_line(bench, run, bool(args.trace), devices)
-    say(f"applied {run.extra['applied']} steps, near ties left out "
-        f"{run.extra['near_ties']}, setup_s {run.setup_s}")
+    notes = "".join(f", {k} {v}" for k, v in run.extra["notes"].items())
+    say(f"applied {run.extra['applied']} steps{notes}, setup_s {run.setup_s}")
     for key, c in line["checks"].items():
         say(f"check {key} = {c['value']} limit {c['limit']}")
     print(json.dumps(line), flush=True)

@@ -20,8 +20,9 @@ the n stratified quantiles of the exponential distribution of mean 1/r
 (Poisson arrivals), in an order drawn from the seed.  Every seed thus
 offers the same number of messages and the same set of gaps, in another
 order: the seed changes which bursts come when, never how much work a run
-holds.  Message payloads come from a seeded pool (``make_pool``); message
-``i`` carries pool entry ``i % len(pool)``.
+holds.  Message payloads come from a seeded pool that the configuration's
+app makes (``bench/apps/<app>.py``); message ``i`` carries pool entry
+``i % len(pool)``.
 """
 
 from __future__ import annotations
@@ -30,7 +31,7 @@ from dataclasses import dataclass
 
 import numpy as np
 
-__all__ = ["Schedule", "schedule", "poisson_gaps", "seed_stream", "make_pool"]
+__all__ = ["Schedule", "schedule", "poisson_gaps", "seed_stream"]
 
 # independent streams drawn from one --seed
 STREAM_TRAFFIC, STREAM_DATA, STREAM_CHECK, STREAM_MODEL = 1, 2, 3, 4
@@ -84,18 +85,3 @@ def schedule(mix: dict, seconds: float, seed: int) -> Schedule:
     due = np.concatenate(due) if due else np.empty(0)
     return Schedule(backlog, due[due < seconds])
 
-
-def make_pool(cfg: dict, seed: int) -> list[np.ndarray]:
-    """``cfg["pool_messages"]`` seeded clustered messages of
-    ``points_per_message`` float32 points: blob centres uniform in
-    ±``scale``, unit Gaussian noise around them."""
-    data = cfg["data"]
-    n, d, p = cfg["points_per_message"], cfg["dim"], cfg["pool_messages"]
-    rng = np.random.default_rng(seed_stream(seed, STREAM_DATA))
-    centres = rng.uniform(-data["scale"], data["scale"], (data["blobs"], d))
-    out = []
-    for _ in range(p):
-        which = rng.integers(0, data["blobs"], n)
-        pts = centres[which] + data["noise"] * rng.normal(size=(n, d))
-        out.append(pts.astype(np.float32))
-    return out

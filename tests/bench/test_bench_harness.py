@@ -29,13 +29,19 @@ from repro.models import kmeans  # noqa: E402
 
 TINY = {"points_per_message": 512, "centroids": 16, "pool_messages": 4,
         "check_first": 4, "check_steps": 4}
+# a model of another kind: read-only weights, prompts of three lengths,
+# logits per message, its step outside the model lock
+TINY_LM = {"name": "tiny-lm", "app": "tiny_lm", "vocab": 64, "width": 32,
+           "prompt_lengths": [8, 16, 24], "pool_messages": 6,
+           "partitions": 2, "batch_max": 1, "check_first": 4,
+           "check_steps": 8, "limits": {"logit_err": 1e-4}}
 SECONDS = 1.0
 
 
 @pytest.fixture(scope="module")
 def tiny_root(tmp_path_factory):
-    """A checkout's benchmark files plus a throwaway configuration, two
-    mixes and a metric, added as new files and entries only."""
+    """A checkout's benchmark files plus throwaway configurations, mixes,
+    a metric and an app, added as new files and entries only."""
     root = tmp_path_factory.mktemp("bench_root")
     shutil.copytree(ROOT / "bench", root / "bench",
                     ignore=shutil.ignore_patterns("__pycache__"))
@@ -47,12 +53,21 @@ def tiny_root(tmp_path_factory):
         json.dumps({"phases": [{"rate_per_s": 150.0}]}))
     (root / "bench/traffic/tiny-drain.json").write_text(
         json.dumps({"backlog": 4000, "reach_per_s": 50}))
+    (root / "bench/configs/tiny-lm.json").write_text(json.dumps(TINY_LM))
+    (root / "bench/traffic/tiny-lm-drain.json").write_text(
+        json.dumps({"backlog": 4000, "reach_per_s": 50}))
+    shutil.copy(ROOT / "tests/bench/data/tiny_lm.py",
+                root / "bench/apps/tiny_lm.py")
     (root / "bench/metrics/committed_msgs.py").write_text(
         "import numpy as np\n\n\ndef read(run):\n"
         "    return float(np.isfinite(run.committed).sum())\n")
-    spec["configs"].append({"name": "tiny", "source": "test",
-                            "file": "bench/configs/tiny.json",
-                            "reduced": [], "why": "tiny"})
+    for c in ("tiny", "tiny-lm"):
+        spec["configs"].append({"name": c, "source": "test",
+                                "file": f"bench/configs/{c}.json",
+                                "reduced": [], "why": "tiny"})
+    spec["workloads"].append({"name": "tiny-lm-drain", "config": "tiny-lm",
+                              "traffic": "tiny-lm-drain", "chips": 1,
+                              "why": "tiny"})
     for t in ("tiny-steady", "tiny-drain"):
         spec["workloads"].append({"name": t, "config": "tiny", "traffic": t,
                                   "chips": 1, "why": "tiny"})
@@ -95,6 +110,11 @@ def test_every_cell_resolves_by_name():
     for cell in spec["workloads"]:
         cfg = bench.config(cell["config"])
         assert cfg["name"] == cell["config"]
+        app = bench.app(cfg["app"])
+        for piece in ("make_pool", "size_bytes", "init_state", "warm_up",
+                      "convert", "make_step", "capture", "check", "control"):
+            assert callable(getattr(app, piece)), piece
+        assert app.STEP_MODULE and app.KERNEL_NAMES
         assert bench.traffic(cell["traffic"])
         assert bench.metrics(cell["name"], traced=False)
         assert bench.metrics(cell["name"], traced=True)
@@ -123,6 +143,37 @@ def test_throwaway_configuration_runs_from_new_files(tiny_root, cell):
         for name in traced:
             assert bench.reader(name)(run) >= 0
     assert line["checks"]["steps_checked"]["value"] >= TINY["check_first"]
+
+
+def test_a_model_of_another_kind_runs_from_new_files(tiny_root):
+    """A model whose step reads its weights and returns an output per
+    message, outside the model lock, taken from new files alone: the
+    harness's own files in the scratch root are the checkout's, byte for
+    byte."""
+    for path in (tiny_root / "bench").rglob("*"):
+        mine = ROOT / path.relative_to(tiny_root)
+        if path.is_file() and mine.exists():
+            assert path.read_bytes() == mine.read_bytes(), mine
+    bench, run = _run(tiny_root, "tiny-lm-drain", 2 ** 31 + 13)
+    line = harness.result_line(bench, run, False, jax.devices()[:1])
+    checks = line["checks"]
+    assert line["correct"], checks
+    assert line["failed"] == 0 and line["attempted"] > 0
+    assert checks["compiles_in_window"]["value"] == 0
+    assert checks["steps_checked"]["value"] >= TINY_LM["check_first"]
+    assert set(checks) == {"logit_err", "delivery_err",
+                           "compiles_in_window", "steps_checked"}
+    assert run.extra["notes"]["tokens"] >= 8 * TINY_LM["check_first"]
+    app = bench.app("tiny_lm")
+    pool = app.make_pool(bench.config("tiny-lm"), 2 ** 31 + 13)
+    assert {len(x) for x in pool} == set(TINY_LM["prompt_lengths"])
+
+    fault = jax.jit(lambda w, x: app.forward(w, x) * (1 + 1e-3))
+    _, bad = _run(tiny_root, "tiny-lm-drain", 2 ** 31 + 13, step=fault)
+    assert bad.extra["correct"] is False
+    logit_err, limit = bad.extra["checks"]["logit_err"]
+    assert logit_err > limit
+    assert bad.extra["checks"]["compiles_in_window"][0] == 0
 
 
 def test_check_positions_lie_within_the_stated_reach():

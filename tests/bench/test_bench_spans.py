@@ -20,9 +20,10 @@ RECORDED = ROOT / "tests/bench/data/kmeans-n8k-c128.xplane.pb.gz"
 
 @pytest.fixture(scope="module")
 def recorded():
+    app = harness.Bench(ROOT).app("kmeans")
     accepted = trace.reduce_trace(str(RECORDED), chips=1,
-                                  step_module=harness.STEP_MODULE,
-                                  kernel_names=harness.KERNEL_NAMES)
+                                  step_module=app.STEP_MODULE,
+                                  kernel_names=app.KERNEL_NAMES)
     return accepted, spans.reduce_spans(str(RECORDED), chips=1)
 
 

@@ -46,9 +46,10 @@ RECORDED = ROOT / "tests/bench/data/kmeans-n8k-c128.xplane.pb.gz"
 @pytest.fixture(scope="module")
 def recorded():
     from bench import harness
+    app = harness.Bench(ROOT).app("kmeans")
     return trace.reduce_trace(str(RECORDED), chips=1,
-                              step_module=harness.STEP_MODULE,
-                              kernel_names=harness.KERNEL_NAMES)
+                              step_module=app.STEP_MODULE,
+                              kernel_names=app.KERNEL_NAMES)
 
 
 def test_recorded_trace_reduces_to_known_numbers(recorded):

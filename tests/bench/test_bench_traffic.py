@@ -6,9 +6,11 @@ from pathlib import Path
 import numpy as np
 import pytest
 
-sys.path.insert(0, str(Path(__file__).resolve().parents[2]))
+ROOT = Path(__file__).resolve().parents[2]
+sys.path.insert(0, str(ROOT))
 
 from bench import traffic  # noqa: E402
+from bench.harness import Bench  # noqa: E402
 
 STEADY = {"backlog": 0, "phases": [{"rate_per_s": 400.0}]}
 
@@ -19,7 +21,8 @@ def test_same_seed_same_schedule_and_pool():
     np.testing.assert_array_equal(a.due, b.due)
     cfg = {"points_per_message": 32, "dim": 9, "pool_messages": 3,
            "data": {"blobs": 4, "scale": 10.0, "noise": 1.0}}
-    for x, y in zip(traffic.make_pool(cfg, 7), traffic.make_pool(cfg, 7)):
+    make_pool = Bench(ROOT).app("kmeans").make_pool
+    for x, y in zip(make_pool(cfg, 7), make_pool(cfg, 7)):
         np.testing.assert_array_equal(x, y)
 
 
