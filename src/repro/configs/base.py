@@ -54,6 +54,9 @@ class ModelConfig:
     experts_per_token: int = 0
     capacity_factor: float = 1.25
     moe_group_size: int = 2048     # GShard-style dispatch group
+    shared_expert_ff: int = 0      # width of an always-on shared expert (0: none)
+    experts_held: int = 0          # experts held on this device (0: all)
+    expert_offset: int = 0         # index of the first held expert
     # --- SSM (Mamba-2 / SSD) ---
     ssm_state: int = 0
     ssm_conv: int = 4
@@ -66,6 +69,11 @@ class ModelConfig:
     local_window: int = 0              # local attention window (0 = full)
     lru_width: int = 0                 # RG-LRU recurrence width (0 = d_model)
     logits_soft_cap: float = 0.0
+    # --- scalar multipliers (Granite 4.0); the defaults change nothing ----
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0   # scales each residual branch
+    attention_multiplier: float = 0.0  # score scale; 0 -> 1/sqrt(head_dim)
+    logits_scaling: float = 1.0        # logits are divided by it
     # --- modality frontend stub ---
     frontend: str | None = None        # None | "audio_frames" | "vision_patches"
     n_prefix: int = 0                  # frontend embedding positions
@@ -109,6 +117,10 @@ class ModelConfig:
         return self.n_experts_padded or self.n_experts
 
     @property
+    def experts_here(self) -> int:
+        return self.experts_held or self.n_experts
+
+    @property
     def n_groups(self) -> int:
         """Number of scanned groups of ``block_pattern``."""
         body = self.n_layers - len(self.tail_pattern)
@@ -120,14 +132,14 @@ class ModelConfig:
     @property
     def is_attention_free(self) -> bool:
         kinds = set(self.block_pattern) | set(self.tail_pattern)
-        return not kinds & {"attn", "local_attn", "moe"}
+        return not kinds & {"attn", "local_attn", "moe", "attn_moe"}
 
     @property
     def is_subquadratic(self) -> bool:
         """True if the arch never attends over the full sequence ("moe"
         blocks carry full GQA attention)."""
         kinds = set(self.block_pattern) | set(self.tail_pattern)
-        return not kinds & {"attn", "moe"}
+        return not kinds & {"attn", "moe", "attn_moe"}
 
     def supports_shape(self, shape: ShapeSpec) -> tuple[bool, str]:
         if shape.name == "long_500k" and not self.is_subquadratic:
@@ -150,11 +162,19 @@ class ModelConfig:
                  + 2 * n_heads_ssm + d_inner * d)
         w = self.lru_width or d
         n_rglru = (d * 2 * w) + 4 * w * 2 + 2 * w + w * d  # proj + conv4 + gates + out
+        # the served MoE: held experts, the full router, the shared expert;
+        # its Mamba-2 mixer counted whole (conv bias, dt bias, gated norm)
+        n_moe_served = (self.experts_here * 3 * d * self.d_ff
+                        + d * self.n_experts + 3 * d * self.shared_expert_ff)
+        n_mamba = (n_ssm + (d_inner + 2 * self.ssm_state) + n_heads_ssm
+                   + d_inner)
         per_kind = {"attn": n_attn + n_mlp_dense,
                     "local_attn": n_attn + n_mlp_dense,
                     "moe": n_attn + n_moe,
                     "ssm": n_ssm,
-                    "rglru": n_rglru + n_mlp_dense}
+                    "rglru": n_rglru + n_mlp_dense,
+                    "ssm_moe": n_mamba + n_moe_served,
+                    "attn_moe": n_attn + n_moe_served}
         kinds = list(self.block_pattern) * self.n_groups + list(self.tail_pattern)
         total = sum(per_kind[k] for k in kinds)
         total += self.vocab_size * d * (1 if self.tie_embeddings else 2)
@@ -165,7 +185,8 @@ class ModelConfig:
         """Parameters touched per token (MoE: only routed experts)."""
         if self.n_experts == 0:
             return self.param_count()
-        dense_like = replace(self, n_experts=self.experts_per_token)
+        dense_like = replace(self, n_experts=self.experts_per_token,
+                             experts_held=0, expert_offset=0)
         return dense_like.param_count()
 
 
@@ -231,10 +252,17 @@ def pad_for_mesh(cfg: ModelConfig, tp: int, pad_kv: bool = False) -> ModelConfig
                    vocab_padded=vp, n_experts_padded=ep)
 
 
+_LOADED = []
+
+
 def _ensure_loaded() -> None:
-    if _REGISTRY:
+    # a config module imported on its own registers itself: that does not
+    # count as loading the registry
+    if _LOADED:
         return
     from repro.configs import (glm4_9b, granite_moe_3b_a800m, internvl2_1b,  # noqa: F401
-                               mamba2_130m, musicgen_medium, qwen2_0_5b,
+                               granite_4_0_h_small, mamba2_130m,
+                               musicgen_medium, qwen2_0_5b,
                                qwen2_5_14b, qwen2_5_3b, qwen3_moe_235b_a22b,
                                recurrentgemma_2b)
+    _LOADED.append(True)

@@ -26,6 +26,16 @@ from jax.experimental.pallas import tpu as pltpu
 DEFAULT_CHUNK = 128
 
 
+def _column(row):
+    """(1, Q) -> (Q, 1): the diagonal of the row's broadcast, summed out
+    (Mosaic moves a vector from lanes to sublanes this way)."""
+    Q = row.shape[1]
+    eye = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0) == \
+        jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
+    return jnp.sum(jnp.where(eye, jnp.broadcast_to(row, (Q, Q)), 0.0),
+                   axis=1, keepdims=True)
+
+
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, hout_ref, h_scr, *,
                 n_chunks):
     c_idx = pl.program_id(1)
@@ -35,36 +45,41 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, hout_ref, h_scr, *,
         h_scr[...] = jnp.zeros_like(h_scr)
 
     x = x_ref[0].astype(jnp.float32)            # (Q, P)
-    dt = dt_ref[0].astype(jnp.float32)          # (Q,)
-    a = a_ref[0].astype(jnp.float32)            # (Q,)  = dt * A  (≤ 0)
+    dt = dt_ref[0].astype(jnp.float32)          # (1, Q)
+    a = a_ref[0].astype(jnp.float32)            # (1, Q)  = dt * A  (≤ 0)
     Bm = b_ref[0].astype(jnp.float32)           # (Q, N)
     Cm = c_ref[0].astype(jnp.float32)           # (Q, N)
     Q = x.shape[0]
 
-    cum = jnp.cumsum(a)                         # (Q,)
+    row = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
+    tri = row >= col
+    # inclusive cumulative sums of a, as a column (Q, 1) and a row (1, Q)
+    cum = jnp.sum(jnp.where(tri, jnp.broadcast_to(a, (Q, Q)), 0.0),
+                  axis=1, keepdims=True)
+    cum_row = jnp.sum(jnp.where(row <= col,
+                                jnp.broadcast_to(_column(a), (Q, Q)), 0.0),
+                      axis=0, keepdims=True)
+    total = jnp.sum(a, axis=1, keepdims=True)   # (1, 1) = cum at the end
     # L[i, j] = exp(cum_i - cum_j) for j <= i (decay from step j+1..i)
-    li = cum[:, None] - cum[None, :]
-    tri = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0) >= \
-        jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
-    L = jnp.where(tri, jnp.exp(li), 0.0)
+    L = jnp.where(tri, jnp.exp(cum - cum_row), 0.0)
 
-    xdt = x * dt[:, None]                       # (Q, P)
+    xdt = x * _column(dt)                       # (Q, P)
     scores = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)  # (Q,Q)
     y_intra = jax.lax.dot_general(scores * L, xdt, (((1,), (0,)), ((), ())),
                                   preferred_element_type=jnp.float32)  # (Q,P)
 
     h = h_scr[...]                              # (P, N)
-    carry_decay = jnp.exp(cum)[:, None]         # (Q, 1)
     y_carry = jax.lax.dot_general(Cm, h, (((1,), (1,)), ((), ())),
-                                  preferred_element_type=jnp.float32) * carry_decay
+                                  preferred_element_type=jnp.float32) * jnp.exp(cum)
     y_ref[0] = (y_intra + y_carry).astype(y_ref.dtype)
 
     # state update: h' = exp(cum_Q) h + Σ_s exp(cum_Q - cum_s) xdt_s ⊗ B_s
-    w = jnp.exp(cum[-1] - cum)[:, None]         # (Q, 1)
+    w = jnp.exp(total - cum)                    # (Q, 1)
     dh = jax.lax.dot_general(xdt * w, Bm, (((0,), (0,)), ((), ())),
                              preferred_element_type=jnp.float32)       # (P, N)
-    h_scr[...] = jnp.exp(cum[-1]) * h + dh
+    h_scr[...] = jnp.exp(total) * h + dh
 
     @pl.when(c_idx == n_chunks - 1)
     def _emit_state():
@@ -74,8 +89,10 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, hout_ref, h_scr, *,
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd_scan_pallas(x, dt, a, Bm, Cm, *, chunk: int = DEFAULT_CHUNK,
                     interpret: bool = False):
-    """x (BH, S, P); dt, a (BH, S); Bm, Cm (Bg, S, N) with BH = Bg·H.
-    Returns (y (BH, S, P) fp32, h_final (BH, P, N) fp32).  S % chunk == 0."""
+    """x (BH, S, P); dt, a (BH, 1, S); Bm, Cm (Bg, S, N) with BH = Bg·H.
+    Returns (y (BH, S, P) fp32, h_final (BH, P, N) fp32).  S % chunk == 0.
+    dt and a are rows (a block's last two dimensions must be whole or
+    tile-aligned: (1, Q) of a (1, S) row is)."""
     BH, S, P = x.shape
     Bg = Bm.shape[0]
     H = BH // Bg
@@ -89,8 +106,8 @@ def ssd_scan_pallas(x, dt, a, Bm, Cm, *, chunk: int = DEFAULT_CHUNK,
         grid=(BH, nc),
         in_specs=[
             pl.BlockSpec((1, Q, P), lambda b, c: (b, c, 0)),
-            pl.BlockSpec((1, Q), lambda b, c: (b, c)),
-            pl.BlockSpec((1, Q), lambda b, c: (b, c)),
+            pl.BlockSpec((1, 1, Q), lambda b, c: (b, 0, c)),
+            pl.BlockSpec((1, 1, Q), lambda b, c: (b, 0, c)),
             pl.BlockSpec((1, Q, N), lambda b, c, H=H: (b // H, c, 0)),
             pl.BlockSpec((1, Q, N), lambda b, c, H=H: (b // H, c, 0)),
         ],
@@ -103,5 +120,8 @@ def ssd_scan_pallas(x, dt, a, Bm, Cm, *, chunk: int = DEFAULT_CHUNK,
             jax.ShapeDtypeStruct((BH, P, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="ssd_scan",
     )(x, dt, a, Bm, Cm)

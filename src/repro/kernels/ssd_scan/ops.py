@@ -29,8 +29,8 @@ def ssd_scan(x, dt, A, Bm, Cm, *, chunk: int = _k.DEFAULT_CHUNK,
     B, S, H, P = x.shape
     N = Bm.shape[-1]
     xb = jnp.moveaxis(x, 2, 1).reshape(B * H, S, P)
-    dtb = jnp.moveaxis(dt, 2, 1).reshape(B * H, S)
-    ab = dtb * jnp.tile(A, B)[:, None]                       # (BH, S) = dt*A
+    dtb = jnp.moveaxis(dt, 2, 1).reshape(B * H, 1, S)
+    ab = dtb * jnp.tile(A, B)[:, None, None]                 # (BH, 1, S) = dt*A
     y, h = _k.ssd_scan_pallas(xb, dtb, ab, Bm, Cm, chunk=min(chunk, S),
                               interpret=interpret)
     y = jnp.moveaxis(y.reshape(B, H, S, P), 1, 2)

@@ -1,14 +1,17 @@
 """Streaming serving driver: the paper's event-driven processing mode applied
 to LM inference.
 
-Requests arrive as broker messages; the engine micro-batches per partition
-and runs prefill + decode compute-units on a pilot (local backend on CPU,
-``jax://mesh`` slices on real hardware).  StreamInsight instruments the run
-(L^br, L^px, T^px per run-id) and the USL-based autoscaler recommends the
-partition count for an offered load.
+Requests arrive as broker messages; the engine hands each partition's
+micro-batches to compute-units on a pilot — a ``jax://`` mesh pilot when a
+TPU is present, the ``local://`` pilot otherwise — and the unit serves each
+micro-batch through ``models.model.serve_request``: the prompts prefilled
+in one program, then one program per greedy token, the tokens staying on
+the device.  StreamInsight instruments the run (L^br, L^px, T^px per run-id)
+and the USL-based autoscaler recommends the partition count for an
+offered load.
 
-    PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b --reduced \
-        --requests 24 --partitions 2 --new-tokens 8
+    PYTHONPATH=src python -m repro.launch.serve --arch granite-4.0-h-small \
+        --reduced --requests 24 --partitions 2 --new-tokens 8
 """
 
 from __future__ import annotations
@@ -46,19 +49,18 @@ def main() -> None:
     metrics = MetricRegistry()
     run_id = new_run_id(f"serve-{cfg.name}")
 
-    # jit one fused generate for the micro-batch size(s) we serve
-    gen = jax.jit(lambda p, prompt: M.greedy_generate(
-        p, cfg, prompt, n_new=args.new_tokens,
-        cache_len=args.prompt_len + args.new_tokens))
-
     def handle(msgs):
         prompts = jnp.stack([jnp.asarray(m.value["tokens"]) for m in msgs])
-        out = gen(params, prompts)
-        return np.asarray(out)
+        out = M.serve_request(params, cfg, prompts, args.new_tokens)
+        return np.asarray(out["tokens"])
 
     pcs = PilotComputeService()
-    pilot = pcs.submit_pilot(PilotDescription(
-        resource="local://", concurrency=args.partitions))
+    if jax.default_backend() == "tpu":
+        desc = PilotDescription(resource="jax://mesh", partitions=args.partitions,
+                                attrs={"mesh_shape": (1,), "mesh_axes": ("data",)})
+    else:
+        desc = PilotDescription(resource="local://", concurrency=args.partitions)
+    pilot = pcs.submit_pilot(desc)
     broker = Broker()
     broker.create_topic("requests", args.partitions)
     engine = ThreadedStreamingEngine(

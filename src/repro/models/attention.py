@@ -80,6 +80,12 @@ def _project_qkv(p, cfg, x, positions):
     return q, k, v
 
 
+def score_scale(cfg) -> float:
+    """The factor on q·k: ``attention_multiplier`` where the config sets
+    one (Granite 4.0), else 1/sqrt(head_dim)."""
+    return cfg.attention_multiplier or 1.0 / math.sqrt(cfg.head_dim)
+
+
 def _repeat_kv(t, cfg):
     """(B, S, KVp, Dh) -> (B, S, Hp, Dh); local (kv replicated, h sharded)."""
     g = cfg.heads_p // cfg.kv_heads_p
@@ -115,7 +121,7 @@ def attend_full(p, cfg, x, positions, window: int = 0):
     q, k, v = _project_qkv(p, cfg, x, positions)
     kh = _repeat_kv(k, cfg).astype(jnp.float32)
     vh = _repeat_kv(v, cfg).astype(jnp.float32)
-    scale = 1.0 / math.sqrt(cfg.head_dim)
+    scale = score_scale(cfg)
     scores = jnp.einsum("bqhk,bshk->bhqs", q.astype(jnp.float32) * scale, kh)
     qpos = positions[..., :, None]
     kpos = positions[..., None, :]
@@ -139,7 +145,7 @@ def attend_chunked(p, cfg, x, positions, window: int = 0):
     H, Dh = cfg.heads_p, cfg.head_dim
     N = S // C
     q, k, v = _project_qkv(p, cfg, x, positions)
-    scale = 1.0 / math.sqrt(Dh)
+    scale = score_scale(cfg)
     pos2d = positions if positions.ndim == 2 else jnp.broadcast_to(
         positions[None], (B, S))
     q_blocks = jnp.moveaxis(q.reshape(B, N, C, H, Dh), 1, 0)        # N B C H Dh
@@ -223,7 +229,6 @@ def cache_specs(window: int = 0):
 def decode_step(p, cfg, x, cache, pos, window: int = 0):
     """x (B, 1, d); pos scalar int32.  Returns (out, new cache)."""
     B = x.shape[0]
-    dh = cfg.head_dim
     positions = jnp.full((B, 1), pos, jnp.int32)
     q, k_new, v_new = _project_qkv(p, cfg, x, positions)
     S = cache["k"].shape[1]
@@ -234,7 +239,7 @@ def decode_step(p, cfg, x, cache, pos, window: int = 0):
                                      (0, slot, 0, 0))
     kh = _repeat_kv(k, cfg).astype(jnp.float32)                     # (B,S,H,Dh)
     vh = _repeat_kv(v, cfg).astype(jnp.float32)
-    scale = 1.0 / math.sqrt(dh)
+    scale = score_scale(cfg)
     s = jnp.einsum("bqhk,bshk->bhqs", q.astype(jnp.float32) * scale, kh)
     idx = jnp.arange(S)
     if window:

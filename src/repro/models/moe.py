@@ -23,6 +23,16 @@ tokens (position ≥ C) pass through the residual untouched.
 
 ``apply_moe_local`` is the identical math on one device (E_loc = E); it is
 the CPU test path and the oracle for the sharded path.
+
+``apply_moe_served`` is the serving layer (Granite 4.0): it drops no token.
+It is told which experts it holds (``cfg.experts_held`` from
+``cfg.expert_offset``), routes over all ``cfg.n_experts`` (top k of the
+router's logits, softmax over the chosen), and computes its own experts'
+part of the result plus the shared expert.  The (token, expert) pairs that
+land here are sorted by expert and run through one grouped matmul
+(``jax.lax.ragged_dot``), so expert work follows the routed pairs, not a
+capacity.  On one chip of an expert-parallel stage the layer runs without
+the exchange: what absent experts would add is left out.
 """
 
 from __future__ import annotations
@@ -38,7 +48,8 @@ from repro.distributed.sharding import constrain, current_rules
 from repro.models.layers import dense_init
 
 __all__ = ["moe_init", "moe_specs", "apply_moe", "apply_moe_local",
-           "apply_moe_ref", "moe_capacity"]
+           "apply_moe_ref", "moe_capacity", "served_moe_init",
+           "served_moe_specs", "apply_moe_served"]
 
 
 def moe_init(key, cfg, dtype):
@@ -306,3 +317,88 @@ def apply_moe_ref(p, cfg, x):
         gate_e = jnp.where(ik == e, gk, 0.0).sum(axis=-1)            # (B,S)
         out = out + ye * gate_e[..., None]
     return out.astype(x.dtype)
+
+
+# ---------------------------------------------------------------------------
+# serving: dropless, held experts, shared expert
+# ---------------------------------------------------------------------------
+
+def served_moe_init(key, cfg, dtype):
+    d, f, fs, held = cfg.d_model, cfg.d_ff, cfg.shared_expert_ff, cfg.experts_here
+    ks = jax.random.split(key, 7)
+    p = {"router": dense_init(ks[0], (d, cfg.n_experts), d, dtype),
+         "w_gate": dense_init(ks[1], (held, d, f), d, dtype),
+         "w_up": dense_init(ks[2], (held, d, f), d, dtype),
+         "w_down": dense_init(ks[3], (held, f, d), f, dtype)}
+    if fs:
+        p["shared"] = {"w_gate": dense_init(ks[4], (d, fs), d, dtype),
+                       "w_up": dense_init(ks[5], (d, fs), d, dtype),
+                       "w_down": dense_init(ks[6], (fs, d), fs, dtype)}
+    return p
+
+
+def served_moe_specs(cfg):
+    p = {"router": (None, None), "w_gate": ("experts", None, None),
+         "w_up": ("experts", None, None), "w_down": ("experts", None, None)}
+    if cfg.shared_expert_ff:
+        p["shared"] = {"w_gate": (None, "ff"), "w_up": (None, "ff"),
+                       "w_down": ("ff", None)}
+    return p
+
+
+def apply_moe_served(p, cfg, x):
+    """x (B, S, d) -> (out (B, S, d), counters).  Every pair routed to a
+    held expert is computed; ``counters`` holds ``pairs`` (pairs routed
+    here), ``max_load`` (the largest held expert's pairs) and ``ids``
+    (B, k), the experts the last position chose over all
+    ``cfg.n_experts``."""
+    B, S, d = x.shape
+    k, held, lo = cfg.experts_per_token, cfg.experts_here, cfg.expert_offset
+    xt = x.reshape(B * S, d)
+    logits = jnp.dot(xt.astype(jnp.float32), p["router"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)          # (T, E)
+    top, ids = jax.lax.top_k(logits, k)
+    gates = jax.nn.softmax(top, axis=-1)                           # (T, k)
+    local = ids - lo
+    here = (local >= 0) & (local < held)
+    group = jnp.where(here, local, held).reshape(-1)               # (T*k,)
+    order = jnp.argsort(group, stable=True)     # pairs here first, by expert
+    sizes = jnp.zeros((held + 1,), jnp.int32).at[group].add(1)[:held]
+    w = jnp.where(here, gates, 0.0)
+    if B * S * k <= held:
+        out = _pairs_one_by_one(p, xt, group, order, jnp.sum(sizes),
+                                w.reshape(-1), k)
+    else:
+        rows = xt[order // k]                                      # (T*k, d)
+        mm = partial(jax.lax.ragged_dot, group_sizes=sizes)
+        h = jax.nn.silu(mm(rows, p["w_gate"])) * mm(rows, p["w_up"])
+        y = mm(h, p["w_down"], preferred_element_type=jnp.float32)
+        # back to (token, choice) order; rows past the held groups are not
+        # computed by the grouped matmul and are masked, never weighted
+        y = y[jnp.argsort(order)].reshape(B * S, k, d)
+        out = jnp.sum(jnp.where(here[..., None], y, 0.0) * w[..., None],
+                      axis=1)
+    if "shared" in p:
+        sp = p["shared"]
+        hs = jax.nn.silu(xt @ sp["w_gate"]) * (xt @ sp["w_up"])
+        out = out + (hs @ sp["w_down"]).astype(jnp.float32)
+    counters = {"pairs": jnp.sum(sizes), "max_load": jnp.max(sizes),
+                "ids": ids.reshape(B, S, k)[:, -1]}
+    return out.astype(x.dtype).reshape(B, S, d), counters
+
+
+def _pairs_one_by_one(p, xt, group, order, n_here, gate, k):
+    """The pairs here, one at a time, for a step with fewer pairs than
+    held experts (a decode token): each reads only its own expert's
+    weights, where a grouped matmul would read every held expert's."""
+    def pair(i, out):
+        j = order[i]
+        e, t = group[j], j // k
+        xr = xt[t][None]                                           # (1, d)
+        expert = lambda w: jax.lax.dynamic_index_in_dim(w, e, keepdims=False)
+        h = jax.nn.silu(xr @ expert(p["w_gate"])) * (xr @ expert(p["w_up"]))
+        y = jnp.dot(h, expert(p["w_down"]), preferred_element_type=jnp.float32)
+        return out.at[t].add(gate[j] * y[0])
+
+    return jax.lax.fori_loop(0, n_here, pair,
+                             jnp.zeros(xt.shape, jnp.float32))

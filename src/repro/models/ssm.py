@@ -8,6 +8,7 @@ in sequence length, which is what makes the ``long_500k`` cell tractable.
 
 Block layout (Mamba-2 defaults): in-proj → causal depthwise conv(4) on
 (x,B,C) → SSD → gated RMSNorm → out-proj.  Scalar A per head; ngroups=1.
+A prefill (``return_state``) runs the SSD through ``kernels/ssd_scan``.
 """
 
 from __future__ import annotations
@@ -18,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.distributed.sharding import constrain
+from repro.kernels.ssd_scan.ops import ssd_scan
 from repro.models.layers import dense_init
 
 __all__ = ["ssm_init", "ssm_specs", "apply_ssm", "ssm_cache_init",
@@ -168,8 +170,13 @@ def apply_ssm(p, cfg, x, h0=None, conv_state=None, return_state=False):
     xi = constrain(xi, ("batch", None, "ssm_inner"))
     A = -jnp.exp(p["A_log"])
     xh = xi.astype(jnp.float32).reshape(B, S, nh, cfg.ssm_head_dim)
-    y, hT = ssd_chunked(xh, dt, A, Bm.astype(jnp.float32),
-                        Cm.astype(jnp.float32), cfg.ssm_chunk, h0)
+    Bm, Cm = Bm.astype(jnp.float32), Cm.astype(jnp.float32)
+    if return_state and h0 is None:
+        # prefill: the SSD kernel (Pallas on TPU, ssd_chunked elsewhere);
+        # training keeps ssd_chunked, which has a gradient
+        y, hT = ssd_scan(xh, dt, A, Bm, Cm, chunk=cfg.ssm_chunk)
+    else:
+        y, hT = ssd_chunked(xh, dt, A, Bm, Cm, cfg.ssm_chunk, h0)
     y = y + xh * p["D"][None, None, :, None]
     y = y.reshape(B, S, di)
     out = _gated_norm(y, z, p["norm_scale"], cfg.norm_eps).astype(x.dtype)

@@ -8,7 +8,12 @@ tail layers run unrolled.
 
 Block kinds: ``attn`` (GQA + MLP), ``local_attn`` (windowed), ``moe``
 (GQA + expert MLP), ``ssm`` (Mamba-2, single residual), ``rglru``
-(Griffin recurrent + MLP).
+(Griffin recurrent + MLP), and the Granite 4.0 kinds ``ssm_moe`` and
+``attn_moe``: a Mamba-2 or GQA mixer followed by the served MoE (dropless,
+held experts, shared expert), whose counters and last position's
+routing ride in the layer's cache.
+A block is its mixer, then its FFN (if any), each a pre-norm residual
+branch scaled by ``cfg.residual_multiplier``.
 """
 
 from __future__ import annotations
@@ -31,7 +36,11 @@ __all__ = ["block_init", "block_specs", "apply_block", "block_cache_init",
            "apply_stack", "stack_cache_init", "stack_cache_specs",
            "decode_stack"]
 
-_ATTN_KINDS = ("attn", "local_attn", "moe")
+# kind -> (mixer, ffn); the mixer's params live under its own key
+_KINDS = {"attn": ("attn", "mlp"), "local_attn": ("attn", "mlp"),
+          "moe": ("attn", "moe"), "ssm": ("ssm", None),
+          "rglru": ("rec", "mlp"), "ssm_moe": ("ssm", "served_moe"),
+          "attn_moe": ("attn", "served_moe")}
 
 
 # ---------------------------------------------------------------------------
@@ -39,36 +48,33 @@ _ATTN_KINDS = ("attn", "local_attn", "moe")
 # ---------------------------------------------------------------------------
 
 def block_init(key, cfg, kind, dtype):
+    if kind not in _KINDS:
+        raise ValueError(kind)
+    mixer, ffn = _KINDS[kind]
     k1, k2, k3, k4 = jax.random.split(key, 4)
     p = {"norm1": norm_init(cfg.d_model, cfg.norm_type, dtype)}
-    if kind in _ATTN_KINDS:
+    if mixer == "attn":
         p["attn"] = attn_mod.attention_init(k1, cfg, dtype)
-        p["norm2"] = norm_init(cfg.d_model, cfg.norm_type, dtype)
-        p["ffn"] = (moe_mod.moe_init(k2, cfg, dtype) if kind == "moe"
-                    else mlp_init(k2, cfg, dtype))
-    elif kind == "ssm":
+    elif mixer == "ssm":
         p["ssm"] = ssm_mod.ssm_init(k3, cfg, dtype)
-    elif kind == "rglru":
-        p["rec"] = rglru_mod.rglru_init(k4, cfg, dtype)
-        p["norm2"] = norm_init(cfg.d_model, cfg.norm_type, dtype)
-        p["ffn"] = mlp_init(k2, cfg, dtype)
     else:
-        raise ValueError(kind)
+        p["rec"] = rglru_mod.rglru_init(k4, cfg, dtype)
+    if ffn:
+        p["norm2"] = norm_init(cfg.d_model, cfg.norm_type, dtype)
+        p["ffn"] = {"mlp": mlp_init, "moe": moe_mod.moe_init,
+                    "served_moe": moe_mod.served_moe_init}[ffn](k2, cfg, dtype)
     return p
 
 
 def block_specs(cfg, kind):
-    p = {"norm1": norm_specs(cfg.norm_type)}
-    if kind in _ATTN_KINDS:
-        p["attn"] = attn_mod.attention_specs(cfg)
+    mixer, ffn = _KINDS[kind]
+    p = {"norm1": norm_specs(cfg.norm_type),
+         mixer: {"attn": attn_mod.attention_specs, "ssm": ssm_mod.ssm_specs,
+                 "rec": rglru_mod.rglru_specs}[mixer](cfg)}
+    if ffn:
         p["norm2"] = norm_specs(cfg.norm_type)
-        p["ffn"] = moe_mod.moe_specs(cfg) if kind == "moe" else mlp_specs(cfg)
-    elif kind == "ssm":
-        p["ssm"] = ssm_mod.ssm_specs(cfg)
-    elif kind == "rglru":
-        p["rec"] = rglru_mod.rglru_specs(cfg)
-        p["norm2"] = norm_specs(cfg.norm_type)
-        p["ffn"] = mlp_specs(cfg)
+        p["ffn"] = {"mlp": mlp_specs, "moe": moe_mod.moe_specs,
+                    "served_moe": moe_mod.served_moe_specs}[ffn](cfg)
     return p
 
 
@@ -76,91 +82,107 @@ def _res(cfg, x):
     return constrain(x, ("batch", "act_seq", None))
 
 
+def _branch(cfg, y):
+    m = cfg.residual_multiplier
+    return y if m == 1.0 else y * jnp.asarray(m, y.dtype)
+
+
+def _apply_ffn(p, cfg, ffn, h):
+    """FFN output and, for the served MoE, its counters (else None)."""
+    if ffn == "served_moe":
+        return moe_mod.apply_moe_served(p, cfg, h)
+    if ffn == "moe":
+        return moe_mod.apply_moe(p, cfg, h), None
+    return apply_mlp(p, cfg, h), None
+
+
+def _with_counters(cache, counters):
+    return cache if counters is None else {**cache, "moe": counters}
+
+
 def apply_block(p, cfg, kind, x, positions, cache=None):
     """Training/prefill forward.  Returns (x, cache_or_None)."""
+    mixer, ffn = _KINDS[kind]
     window = cfg.local_window if kind == "local_attn" else 0
     new_cache = None
-    if kind in _ATTN_KINDS:
-        h = apply_norm(p["norm1"], x, cfg.norm_type, cfg.norm_eps)
+    h = apply_norm(p["norm1"], x, cfg.norm_type, cfg.norm_eps)
+    if mixer == "attn":
         if cache is not None:
             a, new_cache = attn_mod.prefill_into_cache(
                 p["attn"], cfg, h, positions, cache, window)
         else:
             a, _ = attn_mod.attend(p["attn"], cfg, h, positions, window)
-        x = _res(cfg, x + a)
-        h = apply_norm(p["norm2"], x, cfg.norm_type, cfg.norm_eps)
-        f = (moe_mod.apply_moe(p["ffn"], cfg, h) if kind == "moe"
-             else apply_mlp(p["ffn"], cfg, h))
-        x = _res(cfg, x + f)
-    elif kind == "ssm":
-        h = apply_norm(p["norm1"], x, cfg.norm_type, cfg.norm_eps)
-        if cache is not None:
-            s, (hT, conv) = ssm_mod.apply_ssm(p["ssm"], cfg, h, return_state=True)
-            new_cache = {"h": hT, "conv": conv.astype(cache["conv"].dtype)}
-        else:
-            s = ssm_mod.apply_ssm(p["ssm"], cfg, h)
-        x = _res(cfg, x + s)
-    elif kind == "rglru":
-        h = apply_norm(p["norm1"], x, cfg.norm_type, cfg.norm_eps)
-        if cache is not None:
-            r, (hT, conv) = rglru_mod.apply_rglru(p["rec"], cfg, h, return_state=True)
-            new_cache = {"h": hT, "conv": conv.astype(cache["conv"].dtype)}
-        else:
-            r = rglru_mod.apply_rglru(p["rec"], cfg, h)
-        x = _res(cfg, x + r)
-        h = apply_norm(p["norm2"], x, cfg.norm_type, cfg.norm_eps)
-        x = _res(cfg, x + apply_mlp(p["ffn"], cfg, h))
     else:
-        raise ValueError(kind)
+        apply = ssm_mod.apply_ssm if mixer == "ssm" else rglru_mod.apply_rglru
+        if cache is not None:
+            a, (hT, conv) = apply(p[mixer], cfg, h, return_state=True)
+            new_cache = {"h": hT, "conv": conv.astype(cache["conv"].dtype)}
+        else:
+            a = apply(p[mixer], cfg, h)
+    x = _res(cfg, x + _branch(cfg, a))
+    if ffn:
+        h = apply_norm(p["norm2"], x, cfg.norm_type, cfg.norm_eps)
+        f, counters = _apply_ffn(p["ffn"], cfg, ffn, h)
+        x = _res(cfg, x + _branch(cfg, f))
+        if new_cache is not None:
+            new_cache = _with_counters(new_cache, counters)
     return x, new_cache
 
 
+def _counters_init(cfg, batch):
+    return {"pairs": jnp.zeros((), jnp.int32),
+            "max_load": jnp.zeros((), jnp.int32),
+            "ids": jnp.zeros((batch, cfg.experts_per_token), jnp.int32)}
+
+
 def block_cache_init(cfg, kind, batch, cache_len, dtype=jnp.bfloat16):
-    if kind == "attn" or kind == "moe":
-        return attn_mod.init_cache(cfg, batch, cache_len, 0, dtype)
-    if kind == "local_attn":
-        return attn_mod.init_cache(cfg, batch, cache_len, cfg.local_window, dtype)
-    if kind == "ssm":
-        return ssm_mod.ssm_cache_init(cfg, batch, dtype)
-    if kind == "rglru":
-        return rglru_mod.rglru_cache_init(cfg, batch, dtype)
-    raise ValueError(kind)
+    mixer, ffn = _KINDS[kind]
+    if mixer == "attn":
+        window = cfg.local_window if kind == "local_attn" else 0
+        cache = attn_mod.init_cache(cfg, batch, cache_len, window, dtype)
+    elif mixer == "ssm":
+        cache = ssm_mod.ssm_cache_init(cfg, batch, dtype)
+    else:
+        cache = rglru_mod.rglru_cache_init(cfg, batch, dtype)
+    if ffn == "served_moe":
+        cache["moe"] = _counters_init(cfg, batch)
+    return cache
 
 
 def block_cache_specs(cfg, kind):
-    if kind in ("attn", "moe"):
-        return attn_mod.cache_specs(0)
-    if kind == "local_attn":
-        return attn_mod.cache_specs(cfg.local_window)
-    if kind == "ssm":
-        return ssm_mod.ssm_cache_specs(cfg)
-    if kind == "rglru":
-        return rglru_mod.rglru_cache_specs(cfg)
-    raise ValueError(kind)
+    mixer, ffn = _KINDS[kind]
+    if mixer == "attn":
+        specs = attn_mod.cache_specs(cfg.local_window if kind == "local_attn"
+                                     else 0)
+    elif mixer == "ssm":
+        specs = ssm_mod.ssm_cache_specs(cfg)
+    else:
+        specs = rglru_mod.rglru_cache_specs(cfg)
+    if ffn == "served_moe":
+        specs["moe"] = {"pairs": (), "max_load": (), "ids": ("batch", None)}
+    return specs
 
 
 def decode_block(p, cfg, kind, x, cache, pos):
     """One-token decode.  x (B, 1, d); returns (x, new_cache)."""
+    mixer, ffn = _KINDS[kind]
     window = cfg.local_window if kind == "local_attn" else 0
-    if kind in _ATTN_KINDS:
-        h = apply_norm(p["norm1"], x, cfg.norm_type, cfg.norm_eps)
-        a, cache = attn_mod.decode_step(p["attn"], cfg, h, cache, pos, window)
-        x = x + a
+    h = apply_norm(p["norm1"], x, cfg.norm_type, cfg.norm_eps)
+    mix_cache = {k: v for k, v in cache.items() if k != "moe"}
+    if mixer == "attn":
+        a, new_cache = attn_mod.decode_step(p["attn"], cfg, h, mix_cache, pos,
+                                            window)
+    elif mixer == "ssm":
+        a, new_cache = ssm_mod.ssm_decode_step(p["ssm"], cfg, h, mix_cache)
+    else:
+        a, new_cache = rglru_mod.rglru_decode_step(p["rec"], cfg, h, mix_cache)
+    x = x + _branch(cfg, a)
+    if ffn:
         h = apply_norm(p["norm2"], x, cfg.norm_type, cfg.norm_eps)
-        f = (moe_mod.apply_moe(p["ffn"], cfg, h) if kind == "moe"
-             else apply_mlp(p["ffn"], cfg, h))
-        x = x + f
-    elif kind == "ssm":
-        h = apply_norm(p["norm1"], x, cfg.norm_type, cfg.norm_eps)
-        s, cache = ssm_mod.ssm_decode_step(p["ssm"], cfg, h, cache)
-        x = x + s
-    elif kind == "rglru":
-        h = apply_norm(p["norm1"], x, cfg.norm_type, cfg.norm_eps)
-        r, cache = rglru_mod.rglru_decode_step(p["rec"], cfg, h, cache)
-        x = x + r
-        h = apply_norm(p["norm2"], x, cfg.norm_type, cfg.norm_eps)
-        x = x + apply_mlp(p["ffn"], cfg, h)
-    return x, cache
+        f, counters = _apply_ffn(p["ffn"], cfg, ffn, h)
+        x = x + _branch(cfg, f)
+        new_cache = _with_counters(new_cache, counters)
+    return x, new_cache
 
 
 # ---------------------------------------------------------------------------

@@ -86,7 +86,7 @@ def test_greedy_generate_runs(arch, rng):
     cfg = get_config(arch, reduced=True)
     params = M.init_params(rng, cfg)
     prompt = jax.random.randint(rng, (1, 8), 0, cfg.vocab_size, jnp.int32)
-    out = M.greedy_generate(params, cfg, prompt, n_new=4)
+    out = M.serve_request(params, cfg, prompt, n_new=4)["tokens"]
     assert out.shape == (1, 4)
     assert bool((out >= 0).all()) and bool((out < cfg.vocab_size).all())
 

@@ -85,3 +85,41 @@ def test_minibatch_step_compiles_for_v5e_and_fits(one_chip, monkeypatch):
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert used < V5E_HBM_BYTES
     assert mem.temp_size_in_bytes < n * c * 4
+
+
+def test_ssd_scan_kernel_compiles_for_v5e(one_chip):
+    """The Mamba-2 prefill's SSD kernel at granite-4.0-h-small's widths:
+    128 heads of 64, state 128, an 8,192-token prompt, chunks of 256."""
+    from repro.kernels.ssd_scan import ops as ssd_ops
+
+    S, H, P, N = 8192, 128, 64, 128
+    fn = jax.jit(lambda x, dt, A, b, c: ssd_ops.ssd_scan(
+        x, dt, A, b, c, chunk=256, use_pallas=True))
+    compiled = fn.lower(_sds((1, S, H, P), one_chip), _sds((1, S, H), one_chip),
+                        _sds((H,), one_chip), _sds((1, S, N), one_chip),
+                        _sds((1, S, N), one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    y, h = compiled.out_info
+    assert y.shape == (1, S, H, P) and h.shape == (1, H, P, N)
+
+
+def test_served_moe_layer_compiles_for_v5e(one_chip):
+    """One served MoE layer of the benchmark's cut, over an 8,192-token
+    prompt: 18 held experts of 72, top 10, the shared expert; the grouped
+    matmul is the chip's own kernel, and the layer's temporaries fit."""
+    from dataclasses import replace
+
+    from repro.configs.base import get_config
+    from repro.models import moe as moe_mod
+
+    cfg = replace(get_config("granite-4.0-h-small"), experts_held=18)
+    shapes = jax.eval_shape(
+        lambda k: moe_mod.served_moe_init(k, cfg, jnp.bfloat16),
+        jax.random.PRNGKey(0))
+    params = jax.tree.map(lambda a: _sds(a.shape, one_chip, a.dtype), shapes)
+    x = _sds((1, 8192, cfg.d_model), one_chip, jnp.bfloat16)
+    compiled = jax.jit(lambda p, x: moe_mod.apply_moe_served(p, cfg, x)) \
+        .lower(params, x).compile()
+    assert "ragged-dot" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 4 * 2**30
