@@ -7,6 +7,7 @@ import pytest
 
 from repro.kernels.flash_attention import ops as fa_ops
 from repro.kernels.flash_attention.ref import mha_ref
+from repro.kernels.kmeans_distance import kernel as kd_kernel
 from repro.kernels.kmeans_distance import ops as kd_ops
 from repro.kernels.kmeans_distance.ref import assign_ref, pairwise_sq_dists_ref
 from repro.kernels.ssd_scan import ops as ssd_ops
@@ -74,6 +75,114 @@ def test_kmeans_assign_labels_equal_argmin_of_dists(n, k, c_blocks, dups):
                                rtol=1e-6, atol=1e-6)
     if dups:
         assert (np.asarray(labels)[:32] == 5).all()
+
+
+# the packed single pass (d <= 14) on the benchmark's kind of data: blob
+# centres uniform in +-10, unit noise, three centroid panels, near ties
+PACKED_N, PACKED_K = 2000, 4608
+
+
+@pytest.fixture(scope="module")
+def blobs():
+    bn, bc = kd_ops.assign_blocks(PACKED_N, PACKED_K)
+    assert -(-PACKED_K // bc) == 3
+    rng = np.random.default_rng(20171)
+    centres = rng.uniform(-10.0, 10.0, (64, 9))
+    x = centres[rng.integers(0, 64, PACKED_N)] + rng.normal(size=(PACKED_N, 9))
+    c = centres[rng.integers(0, 64, PACKED_K)] + 0.5 * rng.normal(size=(PACKED_K, 9))
+    # near ties across the three panels: centroid 3000 beside centroid 7,
+    # 4100 a near copy of it; points beside 7, and halfway to 3000
+    c[3000] = c[7] + 0.02 * rng.normal(size=9)
+    c[4100] = c[7] + 1e-6
+    x[:16] = c[7] + 0.01 * rng.normal(size=(16, 9))
+    x[16:32] = (c[7] + c[3000]) / 2
+    x, c = x.astype(np.float32), c.astype(np.float32)
+    x64, c64 = x.astype(np.float64), c.astype(np.float64)
+    d64 = ((x64[:, None, :] - c64[None, :, :]) ** 2).sum(-1)
+    xj, cj = jnp.asarray(x), jnp.asarray(c)
+    labels, best = kd_ops.assign(xj, cj, use_pallas=True, interpret=True)
+    packed = kd_ops.pairwise_sq_dists(xj, cj, use_pallas=True, interpret=True)
+    # the float32 HIGHEST formula, as d > 14 runs it
+    pad = lambda a: kd_ops.pad_to_multiple(kd_ops.pad_to_multiple(a, 1, 128), 0, 256)
+    highest = kd_kernel.pairwise_sq_dists_pallas(pad(xj), pad(cj), interpret=True)
+    return dict(x=x, cn=(c64 * c64).sum(1), d64=d64,
+                labels=np.asarray(labels), best=np.asarray(best),
+                packed=np.asarray(packed),
+                highest=np.asarray(highest)[:PACKED_N, :PACKED_K])
+
+
+def test_kmeans_packed_labels_equal_float64_argmin(blobs):
+    d64 = blobs["d64"]
+    part = np.partition(d64, 1, axis=1)
+    sure = part[:, 1] - part[:, 0] > 1e-4
+    assert 16 <= (~sure).sum() < PACKED_N * 0.1
+    np.testing.assert_array_equal(blobs["labels"][sure], d64.argmin(1)[sure])
+    # at a near tie the kernel takes one of the nearest
+    rows = np.arange(PACKED_N)
+    assert (d64[rows, blobs["labels"]] - part[:, 0] <= 1e-4).all()
+
+
+@pytest.mark.parametrize("which", ["best", "packed"])
+def test_kmeans_packed_dists_near_float64(blobs, which):
+    """Within 8 float32 ulps of the norms that cancel (2**-20 (|x|^2 +
+    |c|^2), 1e-4 where they sum to 105): no float32 evaluation of
+    |x|^2 + |c|^2 - 2 x.c gets much closer, however exact its dot."""
+    d64, x = blobs["d64"], blobs["x"].astype(np.float64)
+    scale = (x * x).sum(1)[:, None] + blobs["cn"][None, :]
+    if which == "best":
+        rows = np.arange(PACKED_N)
+        got, want = blobs["best"], d64[rows, blobs["labels"]]
+        scale = scale[rows, blobs["labels"]]
+    else:
+        got, want = blobs["packed"], d64
+    assert (np.abs(got - want) <= 2.0 ** -20 * scale).all()
+
+
+def test_kmeans_packed_error_no_larger_than_highest(blobs):
+    d64 = blobs["d64"]
+    packed_err = np.abs(blobs["packed"] - d64).max()
+    highest_err = np.abs(blobs["highest"] - d64).max()
+    assert packed_err <= highest_err
+
+
+def test_kmeans_split_bf16_sums_back(blobs):
+    x = blobs["x"]
+    pieces = kd_ops.split_bf16(jnp.asarray(x))
+    assert all(p.dtype == jnp.bfloat16 for p in pieces)
+    total = sum(np.asarray(p, np.float64) for p in pieces)
+    assert (np.abs(total - x) <= 2.0 ** -24 * np.abs(x)).all()
+
+
+def _kernel_operand_dtypes(d):
+    """The dtypes and widths the fused assignment's pallas_call receives."""
+    jaxpr = jax.make_jaxpr(lambda a, b: kd_ops.assign(
+        a, b, use_pallas=True, interpret=True))(jnp.zeros((64, d)),
+                                                 jnp.zeros((16, d)))
+
+    def find(jx):
+        for eqn in jx.eqns:
+            if eqn.primitive.name == "pallas_call":
+                return eqn
+            for v in eqn.params.values():
+                inner = getattr(v, "jaxpr", v)
+                if hasattr(inner, "eqns") and (hit := find(inner)):
+                    return hit
+        return None
+
+    eqn = find(jaxpr.jaxpr)
+    return [(str(v.aval.dtype), v.aval.shape[-1]) for v in eqn.invars]
+
+
+@pytest.mark.parametrize("d,packed", [(9, True), (14, True), (15, False),
+                                      (17, False), (130, False)])
+def test_kmeans_packed_path_chosen_from_d(d, packed):
+    assert kd_ops.packs(d) == packed
+    operands = _kernel_operand_dtypes(d)
+    if packed:
+        assert operands[:2] == [("bfloat16", 128)] * 2 and len(operands) == 4
+    else:
+        assert len(operands) == 2
+        assert all(dt == "float32" and w % 128 == 0 for dt, w in operands)
 
 
 # -- flash_attention -----------------------------------------------------------
