@@ -1,6 +1,6 @@
-"""Jitted public wrappers for the K-Means distance kernels.
+"""Jitted public wrappers for the K-Means distance kernel.
 
-Dispatch policy: on TPU the Pallas kernels run compiled; everywhere else the
+Dispatch policy: on TPU the Pallas kernel runs compiled; everywhere else the
 pure-jnp reference executes (XLA fuses it fine on CPU, and the dry-run's
 CPU-hosted compile must not contain TPU-Pallas custom calls).  Tests force
 the Pallas path with ``interpret=True``.
@@ -13,10 +13,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.kmeans_distance import kernel as _k
-from repro.kernels.kmeans_distance.ref import assign_ref, pairwise_sq_dists_ref
+from repro.kernels.kmeans_distance.ref import assign_ref
 
-__all__ = ["pairwise_sq_dists", "assign", "assign_blocks", "pad_to_multiple",
-           "packs", "split_bf16", "pack"]
+__all__ = ["assign", "assign_blocks", "pad_to_multiple", "packed_lanes",
+           "split_bf16", "pack"]
 
 # bf16 pieces that hold a float32 exactly (3 x 8 significant bits)
 PIECES = 3
@@ -38,11 +38,11 @@ def pad_to_multiple(a: jax.Array, axis: int, multiple: int) -> jax.Array:
     return jnp.pad(a, pad)
 
 
-def packs(d: int) -> bool:
-    """Whether d coordinates take the packed single-pass layout: the
-    PIECES x PIECES piece products of each side by side in one pass of
-    the MXU's 128 lanes (d <= 14)."""
-    return PIECES * PIECES * d <= _k.LANES
+def packed_lanes(d: int) -> int:
+    """The lanes of ``pack``'s layout for d coordinates: the PIECES x
+    PIECES piece products of each side by side, rounded up to whole
+    128-lane tiles (128 for d <= 14)."""
+    return -(-PIECES * PIECES * d // _k.LANES) * _k.LANES
 
 
 def split_bf16(a: jax.Array) -> list[jax.Array]:
@@ -60,13 +60,13 @@ def split_bf16(a: jax.Array) -> list[jax.Array]:
 
 
 def _spread(a: jax.Array) -> jax.Array:
-    """(m, d) -> (m, 128) float32: lane b·d + k holds a[:, k] for each of
-    the 9 products b, zeros beyond.  A matmul against 0/1 places the
-    coordinates in one fused pass over the points' own layout; each lane
-    sums one term times 1, exact at ``HIGHEST``."""
+    """(m, d) -> (m, ``packed_lanes(d)``) float32: lane b·d + k holds
+    a[:, k] for each of the 9 products b, zeros beyond.  A matmul against
+    0/1 places the coordinates in one fused pass over the points' own
+    layout; each lane sums one term times 1, exact at ``HIGHEST``."""
     d = a.shape[1]
     lanes = np.arange(PIECES * PIECES * d)
-    place = np.zeros((d, _k.LANES), np.float32)
+    place = np.zeros((d, packed_lanes(d)), np.float32)
     place[lanes % d, lanes] = 1.0
     return jnp.dot(a, jnp.asarray(place), precision=jax.lax.Precision.HIGHEST)
 
@@ -78,14 +78,15 @@ def _select(pieces: list[jax.Array], which: np.ndarray) -> jax.Array:
 
 
 def pack(x: jax.Array, c: jax.Array):
-    """The operands of one bf16 MXU pass for ``packs(d)``: points (n, 128)
-    as [x3 x3 x3 x2 x2 x2 x1 x1 x1], centroids (k, 128) as -2 [c3 c2 c1 c3
-    c2 c1 c3 c2 c1] (a power of two: still exact), so the contraction sums
-    -2 xi.cj over all nine piece pairs, the smallest first; and the
-    float32 norms (n, 1) and (1, k)."""
+    """The operands of one bf16 MXU pass over K = ``packed_lanes(d)``
+    lanes: points (n, K) as [x3 x3 x3 x2 x2 x2 x1 x1 x1], centroids (k, K)
+    as -2 [c3 c2 c1 c3 c2 c1 c3 c2 c1] (a power of two: still exact), so
+    the contraction sums -2 xi.cj over all nine piece pairs, the smallest
+    first; and the float32 norms (n, 1) and (1, k)."""
     x = x.astype(jnp.float32)
     c = c.astype(jnp.float32)
-    block = np.arange(_k.LANES) // x.shape[1]      # 9 and above: zeros
+    d = x.shape[1]
+    block = np.arange(packed_lanes(d)) // d        # 9 and above: zeros
     xp = _select(split_bf16(_spread(x)), 2 - block // 3)
     cp = _select(split_bf16(_spread(-2.0 * c)), 2 - block % 3)
     xn = jnp.sum(x * x, axis=-1, keepdims=True)
@@ -94,32 +95,13 @@ def pack(x: jax.Array, c: jax.Array):
 
 
 def _operands(x: jax.Array, c: jax.Array, bn: int, bc: int):
-    """The kernels' operands: rows padded to the blocks (centroid rows with
-    ``SENTINEL`` coordinates), then ``pack``ed, or for wider d zero-padded
-    to 128 lanes with no norms."""
+    """The kernel's operands: rows padded to the blocks (centroid rows
+    with ``SENTINEL`` coordinates), then ``pack``ed."""
     xp = pad_to_multiple(x, 0, bn)
     cp = pad_to_multiple(c, 0, bc)
     if cp.shape[0] != c.shape[0]:
         cp = cp.at[c.shape[0]:].set(SENTINEL)
-    if packs(x.shape[1]):
-        return pack(xp, cp)
-    return pad_to_multiple(xp, 1, _k.LANES), pad_to_multiple(cp, 1, _k.LANES)
-
-
-def pairwise_sq_dists(x: jax.Array, c: jax.Array, *, use_pallas: bool | None = None,
-                      interpret: bool = False) -> jax.Array:
-    """(n, d), (k, d) -> (n, k) squared Euclidean distances."""
-    if use_pallas is None:
-        use_pallas = _on_tpu()
-    if not use_pallas:
-        return pairwise_sq_dists_ref(x, c)
-    n, k = x.shape[0], c.shape[0]
-    bn = min(_k.DEFAULT_BLOCK_N, max(8, n))
-    bc = min(_k.DEFAULT_BLOCK_C, max(8, k))
-    out = _k.pairwise_sq_dists_pallas(*_operands(x, c, bn, bc),
-                                      block_n=bn, block_c=bc,
-                                      interpret=interpret)
-    return out[:n, :k]
+    return pack(xp, cp)
 
 
 def _blocks(size: int, most: int, multiple: int) -> int:
@@ -131,25 +113,28 @@ def _blocks(size: int, most: int, multiple: int) -> int:
     return -(-size // (n_blocks * multiple)) * multiple
 
 
-def assign_blocks(n: int, k: int) -> tuple[int, int]:
+def assign_blocks(n: int, k: int, d: int) -> tuple[int, int]:
     """(block_n, block_c) of the fused assignment for n points and k
-    centroids: wide centroid panels, so a tile's MXU work outweighs the
-    grid step's fixed cost."""
+    centroids of d coordinates: wide centroid panels, so a tile's MXU work
+    outweighs the grid step's fixed cost, held to the bytes of
+    ``ASSIGN_BLOCK_C`` rows of 128 packed lanes (at least 128 rows)."""
+    most_c = _k.ASSIGN_BLOCK_C * _k.LANES // packed_lanes(d)
+    most_c = max(_k.LANES, most_c // _k.LANES * _k.LANES)
     return (_blocks(n, _k.ASSIGN_BLOCK_N, 8),
-            _blocks(k, _k.ASSIGN_BLOCK_C, _k.LANES))
+            _blocks(k, most_c, _k.LANES))
 
 
 def assign(x: jax.Array, c: jax.Array, *, use_pallas: bool | None = None,
            interpret: bool = False):
     """Fused assignment -> (labels (n,) int32, best_sq_dist (n,) f32):
-    the argmin, lowest index first, of the distances
-    ``pairwise_sq_dists`` gives, without the (n, k) matrix."""
+    the argmin, lowest index first, of the squared distances, without the
+    (n, k) matrix."""
     if use_pallas is None:
         use_pallas = _on_tpu()
     if not use_pallas:
         return assign_ref(x, c)
-    n, k = x.shape[0], c.shape[0]
-    bn, bc = assign_blocks(n, k)
+    (n, d), k = x.shape, c.shape[0]
+    bn, bc = assign_blocks(n, k, d)
     labels, best = _k.assign_pallas(*_operands(x, c, bn, bc),
                                     block_n=bn, block_c=bc,
                                     interpret=interpret)

@@ -68,9 +68,3 @@ def inertia(points: jax.Array, centroids: jax.Array) -> jax.Array:
     _, best = assign(points, centroids)
     return jnp.mean(best)
 
-
-def flops_estimate(n: int, c: int, d: int) -> float:
-    """Analytic FLOPs of one minibatch step (distance phase dominates: 3ncd)."""
-    distance = 3.0 * n * c * d
-    update = 2.0 * n * c + 2.0 * n * d + 6.0 * c * d
-    return distance + update

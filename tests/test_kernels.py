@@ -7,7 +7,6 @@ import pytest
 
 from repro.kernels.flash_attention import ops as fa_ops
 from repro.kernels.flash_attention.ref import mha_ref
-from repro.kernels.kmeans_distance import kernel as kd_kernel
 from repro.kernels.kmeans_distance import ops as kd_ops
 from repro.kernels.kmeans_distance.ref import assign_ref, pairwise_sq_dists_ref
 from repro.kernels.ssd_scan import ops as ssd_ops
@@ -19,26 +18,15 @@ KEY = jax.random.PRNGKey(0)
 # -- kmeans_distance ----------------------------------------------------------
 
 @pytest.mark.parametrize("n,k,d", [(64, 16, 9), (256, 128, 9), (128, 300, 32),
-                                   (512, 64, 130)])
+                                   (512, 64, 130), (256, 100, 17)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_kmeans_dists_matches_ref(n, k, d, dtype):
+def test_kmeans_assign_matches_ref(n, k, d, dtype):
     kx, kc = jax.random.split(KEY)
     x = jax.random.normal(kx, (n, d), dtype)
     c = jax.random.normal(kc, (k, d), dtype)
-    got = kd_ops.pairwise_sq_dists(x, c, use_pallas=True, interpret=True)
-    want = pairwise_sq_dists_ref(x.astype(jnp.float32), c.astype(jnp.float32))
-    tol = 1e-5 if dtype == jnp.float32 else 2e-2
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=tol, atol=tol * d)
-
-
-@pytest.mark.parametrize("n,k,d", [(64, 16, 9), (256, 100, 17)])
-def test_kmeans_assign_matches_ref(n, k, d):
-    kx, kc = jax.random.split(KEY)
-    x = jax.random.normal(kx, (n, d), jnp.float32)
-    c = jax.random.normal(kc, (k, d), jnp.float32)
     labels, best = kd_ops.assign(x, c, use_pallas=True, interpret=True)
-    ref_labels, ref_best = assign_ref(x, c)
+    x, c = x.astype(jnp.float32), c.astype(jnp.float32)
+    _, ref_best = assign_ref(x, c)
     np.testing.assert_allclose(np.asarray(best), np.asarray(ref_best),
                                rtol=1e-5, atol=1e-5)
     # ties can flip labels; verify via distance equality instead of identity
@@ -46,6 +34,19 @@ def test_kmeans_assign_matches_ref(n, k, d):
     np.testing.assert_allclose(
         np.asarray(d2[np.arange(n), np.asarray(labels)]), np.asarray(ref_best),
         rtol=1e-5, atol=1e-5)
+
+
+def _float64_dists(x, c):
+    x64, c64 = np.asarray(x, np.float64), np.asarray(c, np.float64)
+    return ((x64[:, None, :] - c64[None, :, :]) ** 2).sum(-1)
+
+
+def _cancel_bound(x, c):
+    """8 float32 ulps of the norms that cancel, 2**-20 (|x|^2 + |c|^2): no
+    float32 evaluation of |x|^2 + |c|^2 - 2 x.c gets much closer, however
+    exact its dot."""
+    x64, c64 = np.asarray(x, np.float64), np.asarray(c, np.float64)
+    return 2.0 ** -20 * ((x64 * x64).sum(1)[:, None] + (c64 * c64).sum(1)[None, :])
 
 
 @pytest.mark.parametrize("n,k,c_blocks,dups", [
@@ -57,8 +58,9 @@ def test_kmeans_assign_matches_ref(n, k, d):
 ], ids=["ragged-n", "one-block", "three-blocks", "sentinels", "ties"])
 def test_kmeans_assign_labels_equal_argmin_of_dists(n, k, c_blocks, dups):
     """At the model's own block sizes, the fused kernel's labels are the
-    argmin of the distance matrix, lowest index first at ties."""
-    bn, bc = kd_ops.assign_blocks(n, k)
+    float64 argmin away from near ties, and the lowest index at exact
+    ties."""
+    bn, bc = kd_ops.assign_blocks(n, k, 9)
     assert (n % bn != 0) == (n == 300)
     assert -(-k // bc) == c_blocks and (k % bc != 0) == (k in (1000, 4500))
     kx, kc = jax.random.split(KEY)
@@ -68,23 +70,28 @@ def test_kmeans_assign_labels_equal_argmin_of_dists(n, k, c_blocks, dups):
         c = c.at[jnp.asarray(dups)].set(c[5])
         x = x.at[:32].set(c[5] + 0.01 * x[:32])
     labels, best = kd_ops.assign(x, c, use_pallas=True, interpret=True)
-    d2 = np.asarray(kd_ops.pairwise_sq_dists(x, c, use_pallas=True,
-                                             interpret=True))
-    np.testing.assert_array_equal(np.asarray(labels), np.argmin(d2, axis=1))
-    np.testing.assert_allclose(np.asarray(best), d2.min(axis=1),
-                               rtol=1e-6, atol=1e-6)
+    labels, best = np.asarray(labels), np.asarray(best)
+    d64 = _float64_dists(x, c)
+    part = np.partition(d64, 1, axis=1)
+    sure = part[:, 1] - part[:, 0] > 1e-4
+    assert sure[32 if dups else 0:].all()
+    np.testing.assert_array_equal(labels[sure], d64.argmin(1)[sure])
+    rows = np.arange(n)
+    assert (d64[rows, labels] - part[:, 0] <= 1e-4).all()
+    assert (np.abs(best - d64[rows, labels])
+            <= _cancel_bound(x, c)[rows, labels]).all()
     if dups:
-        assert (np.asarray(labels)[:32] == 5).all()
+        assert (labels[:32] == 5).all()
 
 
-# the packed single pass (d <= 14) on the benchmark's kind of data: blob
-# centres uniform in +-10, unit noise, three centroid panels, near ties
+# the packed single pass on the benchmark's kind of data: blob centres
+# uniform in +-10, unit noise, three centroid panels, near ties
 PACKED_N, PACKED_K = 2000, 4608
 
 
 @pytest.fixture(scope="module")
 def blobs():
-    bn, bc = kd_ops.assign_blocks(PACKED_N, PACKED_K)
+    bn, bc = kd_ops.assign_blocks(PACKED_N, PACKED_K, 9)
     assert -(-PACKED_K // bc) == 3
     rng = np.random.default_rng(20171)
     centres = rng.uniform(-10.0, 10.0, (64, 9))
@@ -97,18 +104,19 @@ def blobs():
     x[:16] = c[7] + 0.01 * rng.normal(size=(16, 9))
     x[16:32] = (c[7] + c[3000]) / 2
     x, c = x.astype(np.float32), c.astype(np.float32)
-    x64, c64 = x.astype(np.float64), c.astype(np.float64)
-    d64 = ((x64[:, None, :] - c64[None, :, :]) ** 2).sum(-1)
     xj, cj = jnp.asarray(x), jnp.asarray(c)
     labels, best = kd_ops.assign(xj, cj, use_pallas=True, interpret=True)
-    packed = kd_ops.pairwise_sq_dists(xj, cj, use_pallas=True, interpret=True)
-    # the float32 HIGHEST formula, as d > 14 runs it
-    pad = lambda a: kd_ops.pad_to_multiple(kd_ops.pad_to_multiple(a, 1, 128), 0, 256)
-    highest = kd_kernel.pairwise_sq_dists_pallas(pad(xj), pad(cj), interpret=True)
-    return dict(x=x, cn=(c64 * c64).sum(1), d64=d64,
+    # the kernel tile's arithmetic over the whole matrix
+    xp, cp, xn, cn = kd_ops.pack(xj, cj)
+    dot = jax.lax.dot_general(xp, cp, (((1,), (1,)), ((), ())),
+                              preferred_element_type=jnp.float32)
+    packed = jnp.maximum(xn + cn + dot, 0.0)
+    # the float32 formula, contracted at HIGHEST
+    with jax.default_matmul_precision("highest"):
+        highest = pairwise_sq_dists_ref(xj, cj)
+    return dict(x=x, scale=_cancel_bound(x, c), d64=_float64_dists(x, c),
                 labels=np.asarray(labels), best=np.asarray(best),
-                packed=np.asarray(packed),
-                highest=np.asarray(highest)[:PACKED_N, :PACKED_K])
+                packed=np.asarray(packed), highest=np.asarray(highest))
 
 
 def test_kmeans_packed_labels_equal_float64_argmin(blobs):
@@ -124,18 +132,16 @@ def test_kmeans_packed_labels_equal_float64_argmin(blobs):
 
 @pytest.mark.parametrize("which", ["best", "packed"])
 def test_kmeans_packed_dists_near_float64(blobs, which):
-    """Within 8 float32 ulps of the norms that cancel (2**-20 (|x|^2 +
-    |c|^2), 1e-4 where they sum to 105): no float32 evaluation of
-    |x|^2 + |c|^2 - 2 x.c gets much closer, however exact its dot."""
-    d64, x = blobs["d64"], blobs["x"].astype(np.float64)
-    scale = (x * x).sum(1)[:, None] + blobs["cn"][None, :]
+    """Within 8 float32 ulps of the norms that cancel (1e-4 where they
+    sum to 105)."""
+    d64, scale = blobs["d64"], blobs["scale"]
     if which == "best":
         rows = np.arange(PACKED_N)
         got, want = blobs["best"], d64[rows, blobs["labels"]]
         scale = scale[rows, blobs["labels"]]
     else:
         got, want = blobs["packed"], d64
-    assert (np.abs(got - want) <= 2.0 ** -20 * scale).all()
+    assert (np.abs(got - want) <= scale).all()
 
 
 def test_kmeans_packed_error_no_larger_than_highest(blobs):
@@ -173,16 +179,13 @@ def _kernel_operand_dtypes(d):
     return [(str(v.aval.dtype), v.aval.shape[-1]) for v in eqn.invars]
 
 
-@pytest.mark.parametrize("d,packed", [(9, True), (14, True), (15, False),
-                                      (17, False), (130, False)])
-def test_kmeans_packed_path_chosen_from_d(d, packed):
-    assert kd_ops.packs(d) == packed
+@pytest.mark.parametrize("d,lanes", [(9, 128), (14, 128), (15, 256),
+                                     (17, 256), (130, 1280)])
+def test_kmeans_packed_path_chosen_from_d(d, lanes):
+    """Every d takes the packed layout, in whole 128-lane tiles of 9 d."""
     operands = _kernel_operand_dtypes(d)
-    if packed:
-        assert operands[:2] == [("bfloat16", 128)] * 2 and len(operands) == 4
-    else:
-        assert len(operands) == 2
-        assert all(dt == "float32" and w % 128 == 0 for dt, w in operands)
+    assert len(operands) == 4
+    assert operands[:2] == [("bfloat16", lanes)] * 2
 
 
 # -- flash_attention -----------------------------------------------------------

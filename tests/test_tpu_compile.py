@@ -54,19 +54,14 @@ def _sds(shape, sharding, dtype=jnp.float32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-@pytest.mark.parametrize("n,c", [LARGE, SMALL], ids=["26000x8192", "8000x1024"])
-def test_pairwise_sq_dists_compiles_for_v5e(one_chip, n, c):
-    fn = jax.jit(lambda x, cc: kd_ops.pairwise_sq_dists(x, cc, use_pallas=True))
-    compiled = fn.lower(_sds((n, DIM), one_chip), _sds((c, DIM), one_chip)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-
-
-def test_fused_assign_compiles_for_v5e(one_chip):
-    """The kernel keeps the name the benchmark's trace finds it by, and at
-    d = 9 contracts the packed bf16 operands, 128 lanes wide."""
-    n, c = LARGE
+@pytest.mark.parametrize("n,c,d,lanes", [(*LARGE, DIM, 128), (*SMALL, DIM, 128),
+                                         (*LARGE, 130, 1280)],
+                         ids=["26000x8192x9", "8000x1024x9", "26000x8192x130"])
+def test_fused_assign_compiles_for_v5e(one_chip, n, c, d, lanes):
+    """The kernel keeps the name the benchmark's trace finds it by, and
+    contracts the packed bf16 operands, ceil(9 d / 128) lane tiles wide."""
     fn = jax.jit(lambda x, cc: kd_ops.assign(x, cc, use_pallas=True))
-    compiled = fn.lower(_sds((n, DIM), one_chip), _sds((c, DIM), one_chip)).compile()
+    compiled = fn.lower(_sds((n, d), one_chip), _sds((c, d), one_chip)).compile()
     calls = [ln for ln in compiled.as_text().splitlines()
              if 'custom_call_target="tpu_custom_call"' in ln]
     assert len(calls) == 1
@@ -74,7 +69,7 @@ def test_fused_assign_compiles_for_v5e(one_chip):
     assert "assign_pallas" in name
     operands = calls[0].split("operand_layout_constraints=", 1)[1]
     assert re.findall(r"(\w+)\[\d+,(\d+)\]", operands)[:2] \
-        == [("bf16", "128")] * 2
+        == [("bf16", str(lanes))] * 2
     labels, best = compiled.out_info
     assert labels.shape == (n,) and best.shape == (n,)
 
